@@ -37,6 +37,8 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import (api_bench, backends, compile_bench, figures, place_bench,
                    roofline, serve_bench, tables, traffic_bench,
                    vectorvm_bench)

@@ -80,7 +80,7 @@ def _bench_cell(compiled, app, batch: int) -> dict:
 def serve_batching(rows: list[dict], out_path: str = BENCH_JSON) -> None:
     """Batched-vs-sequential serving throughput -> rows + BENCH_serve.json."""
     from repro.core.backend import JaxBackend
-    jax_be = JaxBackend()            # auto route: Pallas on TPU, XLA else
+    jax_be = JaxBackend()            # auto route: XLA (jnp) everywhere
     apps_payload: dict[str, dict] = {}
     mismatched: list[str] = []
     for name in sorted(ALL_APPS):

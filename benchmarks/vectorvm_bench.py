@@ -62,7 +62,7 @@ def _lane_stats(stats) -> dict:
 
 def vectorvm_backends(rows: list[dict], out_path: str = BENCH_JSON) -> None:
     """numpy / windowed-jax / resident timings -> rows + BENCH_vectorvm.json."""
-    jax_be = JaxBackend()            # auto route: Pallas on TPU, XLA else
+    jax_be = JaxBackend()            # auto route: XLA (jnp) everywhere
     baseline = {}
     if os.environ.get("REVET_VECTORVM_GATE") == "1" and \
             os.path.exists(out_path):

@@ -46,6 +46,20 @@ def hash_table_program(m, table_k, table_v, queries, results, *, count,
         b.dram_store(results, i, res)
 
 
+def make_lookups(rng: np.random.Generator, keys: np.ndarray,
+                 n_lookups: int) -> np.ndarray:
+    """A query set against ``keys``: 75% hits, 25% misses (keys drawn
+    from ``[1, 2**20)``, misses from ``[2**20, 2**21)``)."""
+    hit = rng.random(n_lookups) < 0.75
+    return np.where(hit, rng.choice(keys, n_lookups),
+                    rng.integers(1 << 20, 1 << 21, n_lookups))
+
+
+def reference_lookup(kv: dict, lookups: np.ndarray) -> np.ndarray:
+    """Plain-Python reference: the stored value, or 0 for a miss."""
+    return np.array([kv.get(int(q), 0) for q in lookups])
+
+
 def build(n_lookups: int = 64, n_slots: int = 256, load: float = 0.25,
           seed: int = 0) -> App:
     rng = np.random.default_rng(seed)
@@ -62,17 +76,14 @@ def build(n_lookups: int = 64, n_slots: int = 256, load: float = 0.25,
         table_k[h] = k
         table_v[h] = v
 
-    # lookups: 75% hits, 25% misses
-    hit = rng.random(n_lookups) < 0.75
-    lookups = np.where(hit, rng.choice(keys, n_lookups),
-                       rng.integers(1 << 20, 1 << 21, n_lookups))
+    lookups = make_lookups(rng, keys, n_lookups)
 
     # duplicated-at-wrap table copy so linear probes never wrap (load 25%)
     tk2 = np.concatenate([table_k, table_k])
     tv2 = np.concatenate([table_v, table_v])
 
-    kv = dict(zip(map(int, keys), map(int, vals)))
-    expected = np.array([kv.get(int(q), 0) for q in lookups])
+    expected = reference_lookup(dict(zip(map(int, keys), map(int, vals))),
+                                lookups)
     return make_app(
         hash_table_program, name="hash_table",
         inputs={"table_k": tk2, "table_v": tv2, "queries": lookups},

@@ -13,11 +13,10 @@ Two implementations:
   TokenVM-validated oracle; every other backend must match it exactly
   (values *and* token counts).
 * :class:`JaxBackend` — dispatches through the executor-facing entry points
-  in ``kernels/ops.py``. Two routes: ``"pallas"`` drives the real TPU kernels
-  (``stream_compact``'s one-hot-matmul compaction, ``segment_reduce``'s
-  windowed reduction; interpret mode on CPU), ``"jnp"`` is the jit'd XLA
-  fallback used where Pallas CPU lowering is impractically slow (same policy
-  as the rest of ``kernels/ops.py``). ``route="auto"`` picks Pallas on TPU.
+  in ``kernels/ops.py``. Two routes: ``"jnp"`` is the jit'd XLA path, and
+  the one ``route="auto"`` picks on every platform; ``"pallas"`` drives the
+  ``stream_compact`` / ``segment_reduce`` Pallas kernels in interpret mode
+  only, because the TPU compiler refuses both (see :class:`JaxBackend`).
 
 All backends exchange data at a fixed boundary: int64 numpy arrays whose
 values respect the 32-bit wrap discipline of the IR (``ir.wrap32``). That
@@ -385,23 +384,34 @@ class NumpyBackend(ExecutorBackend):
 class JaxBackend(ExecutorBackend):
     """Dispatch through ``kernels/ops.py`` executor entry points.
 
-    ``route="pallas"`` drives the Pallas kernels (interpret mode off-TPU);
-    ``route="jnp"`` uses the jit'd XLA fallbacks; ``route="auto"`` picks
-    Pallas iff running on a TPU — the same policy the LM-stack wrappers in
-    ``kernels/ops.py`` follow.
+    ``route="jnp"`` (what ``route="auto"`` resolves to on every platform)
+    runs the jit'd XLA ops; ``route="pallas"`` drives the windowed Pallas
+    kernels, and only in interpret mode: the TPU compiler refuses both of
+    them (``stream_compact``'s rank-1 count block is not a multiple of the
+    128-lane tiling; ``segment_reduce`` stores scalars to VMEM, and both
+    use ``cumsum``, which Pallas TPU does not lower), so a compiled Pallas
+    route raises here instead of deep inside a launch.
     """
 
     def __init__(self, route: str = "auto", interpret: bool | None = None):
         import jax                       # deferred: numpy backend stays light
         from ..kernels import ops as _ops
         self._ops = _ops
-        on_tpu = jax.default_backend() == "tpu"
         if route == "auto":
-            route = "pallas" if on_tpu else "jnp"
+            route = "jnp"
         if route not in ("pallas", "jnp"):
             raise ValueError(f"unknown JaxBackend route {route!r}")
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        if route == "pallas" and not interpret:
+            raise ValueError(
+                "JaxBackend(route='pallas') runs only in interpret mode: the "
+                "TPU compiler refuses stream_compact (rank-1 (1,) count "
+                "block, not a multiple of the 128 tiling) and segment_reduce "
+                "(scalar stores to VMEM; cumsum has no Pallas TPU lowering). "
+                "Use route='jnp' (the 'auto' default) or interpret=True")
         self.route = route
-        self.interpret = (not on_tpu) if interpret is None else bool(interpret)
+        self.interpret = bool(interpret)
         self.name = f"jax[{route}]"
 
     supports_resident = True

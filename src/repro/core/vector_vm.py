@@ -16,11 +16,11 @@ vectors*. Each context processes up to ``VLEN`` tokens per tick:
 The lane-level primitives behind all four bullets live behind the pluggable
 :class:`~repro.core.backend.ExecutorBackend` (``core/backend.py``):
 ``backend="numpy"`` is the bit-exact TokenVM-validated oracle,
-``backend="jax"`` dispatches through ``kernels/ops.py`` onto the Pallas
-kernels (interpret mode on CPU, the real thing on TPU). The scheduler —
-heads, queues, back-pressure, memory — is backend-agnostic; both backends
-must produce identical outputs *and* identical ``stats`` token counts
-(``tests/test_backends.py`` enforces this on every app).
+``backend="jax"`` dispatches through ``kernels/ops.py`` onto jit'd XLA ops
+(the Pallas kernels only in interpret mode, see ``JaxBackend``). The
+scheduler — heads, queues, back-pressure, memory — is backend-agnostic;
+both backends must produce identical outputs *and* identical ``stats``
+token counts (``tests/test_backends.py`` enforces this on every app).
 
 The scheduler runs in *supersteps*: each tick snapshots the set of ready
 contexts (tokens waiting and output room available) and fires them all,

@@ -98,8 +98,10 @@ class AsyncServeEngine:
     ``advance_ticks`` is the superstep quantum one ``pump()`` drives the
     open wave, which bounds how long admission decisions are deferred;
     ``execution`` picks the launch mode (``None`` follows the compiled
-    options; resident silently falls back to windowed on backends without
-    a resident path and under supervisor degradation); ``clock`` injects a
+    options; resident on a backend without a resident path raises, and
+    resident degrades to windowed under supervisor degradation or for
+    graphs the resident loop cannot express — both visible in
+    :meth:`stats`); ``clock`` injects a
     monotonic time source (tests run on virtual time).  ``fault_hook``
     (``hook(attempt, mode, requests)``) is the chaos-engineering seam: it
     runs before every launch attempt and may raise to simulate failures.
@@ -148,7 +150,9 @@ class AsyncServeEngine:
         if requested not in ("windowed", "resident"):
             raise ValueError(f"unknown execution mode {requested!r}")
         if requested == "resident" and not self.backend.supports_resident:
-            requested = "windowed"
+            raise ValueError(
+                f"execution='resident': backend {self.backend.name!r} has no "
+                "resident path (use backend='jax' or execution='windowed')")
         self._execution = requested
         # per-tenant FIFO queues, round-robin cursor in first-seen order
         self._queues: dict[str, list[AsyncRequest]] = {}
@@ -168,6 +172,8 @@ class AsyncServeEngine:
         self.queue_depth_peak = 0
         self.queue_s_total = 0.0
         self.warmup_launches = 0
+        self.warmup_s = {"resident": 0.0, "windowed": 0.0}  # wall, per mode
+        self.launch_walls: list[tuple[str, int, float]] = []  # mode, size, s
 
     # ------------------------------------------------------------ admission
     @property
@@ -302,6 +308,8 @@ class AsyncServeEngine:
         slo = req.slo_s if req.slo_s is not None else self.slo_s
         met = (latency <= slo) if slo is not None else None
         self.counters["served"] += 1
+        if self._execution == "resident" and report.execution != "resident":
+            self.counters["windowed_instead_of_resident"] += 1
         if met is True:
             self.counters["slo_met"] += 1
         elif met is False:
@@ -427,6 +435,7 @@ class AsyncServeEngine:
         except Exception as e:           # noqa: BLE001 — retries exhausted
             return [self._resolve_failed(r, e) for r in reqs]
         self.launch_counts[len(reqs)] += 1
+        self.launch_walls.append(("windowed", len(reqs), bx.report.wall_s))
         return [self._resolve_ok(r, ex) for r, ex in zip(reqs, bx)]
 
     # resident: closed bucketed launches ------------------------------------
@@ -471,6 +480,8 @@ class AsyncServeEngine:
         size = len(reqs) if not self.bucket_sizes else \
             bucket_launch_size(len(reqs), self.bucket_sizes)
         self.launch_counts[size] += 1
+        self.launch_walls.append((bx.report.execution, size,
+                                  bx.report.wall_s))
         return [self._resolve_ok(r, ex) for r, ex in zip(batch, bx)]
 
     # --------------------------------------------------------------- warmup
@@ -493,6 +504,7 @@ class AsyncServeEngine:
                             for n in range(1, self.max_wave + 1)})
         else:
             sizes = sorted(set(int(b) for b in buckets))
+        t0 = time.perf_counter()
         if self._execution == "resident":
             for b in sizes:
                 self.compiled.execute_batch(
@@ -501,6 +513,8 @@ class AsyncServeEngine:
                     bucket_sizes=self.bucket_sizes, **self._vm_kwargs)
                 self.warmup_launches += 1
                 warmed["resident"].append(b)
+        t1 = time.perf_counter()
+        self.warmup_s["resident"] += t1 - t0
         # the windowed wave path serves degraded mode (and is the only
         # mode on non-resident backends): one full wave warms the
         # backend's window-shaped kernel caches
@@ -509,6 +523,7 @@ class AsyncServeEngine:
         for _ in range(self.max_wave):
             s.admit(dict(arrays), scalars, require_inputs=False)
         s.finish(max_ticks=self.max_wave_ticks)
+        self.warmup_s["windowed"] += time.perf_counter() - t1
         self.warmup_launches += 1
         warmed["windowed"].append(self.max_wave)
         return warmed
@@ -530,6 +545,8 @@ class AsyncServeEngine:
             "mid_wave_admissions": int(
                 self.counters["mid_wave_admissions"]),
             "resident_fallbacks": int(self.counters["resident_fallbacks"]),
+            "windowed_instead_of_resident": int(
+                self.counters["windowed_instead_of_resident"]),
             "slo_met": int(self.counters["slo_met"]),
             "slo_missed": int(self.counters["slo_missed"]),
             "queue_depth": self.queue_depth,
@@ -540,6 +557,7 @@ class AsyncServeEngine:
             "launches": sum(self.launch_counts.values()),
             "launches_by_bucket": dict(sorted(self.launch_counts.items())),
             "warmup_launches": self.warmup_launches,
+            "warmup_s": dict(self.warmup_s),
             "tenant_served": dict(sorted(self.tenant_served.items())),
             "supervisor_retries": self.supervisor.retries,
             "supervisor_failures": self.supervisor.failures,

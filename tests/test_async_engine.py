@@ -332,10 +332,12 @@ def test_async_stats_keys_complete():
     st = eng.stats()
     for key in ("backend", "execution", "mode", "degraded", "submitted",
                 "served", "shed", "failed", "waves", "wave_timeouts",
-                "mid_wave_admissions", "resident_fallbacks", "slo_met",
+                "mid_wave_admissions", "resident_fallbacks",
+                "windowed_instead_of_resident", "slo_met",
                 "slo_missed", "queue_depth", "queue_depth_peak",
                 "time_in_queue_s", "time_in_queue_mean_s", "launches",
-                "launches_by_bucket", "warmup_launches", "tenant_served",
+                "launches_by_bucket", "warmup_launches", "warmup_s",
+                "tenant_served",
                 "supervisor_retries", "supervisor_failures", "stragglers"):
         assert key in st, key
     assert st["mode"] == "windowed" and st["launches_by_bucket"] == {1: 1}
@@ -354,6 +356,7 @@ def test_resident_async_bucketed_launches():
     assert eng.mode() == "resident"
     warmed = eng.warmup(dict(app.dram_init), dict(app.params))
     assert warmed["resident"] == [1, 2]
+    assert all(s > 0 for s in eng.stats()["warmup_s"].values())
     for _ in range(3):
         eng.submit(_req(app))
     done = eng.run_until_idle()
@@ -363,6 +366,9 @@ def test_resident_async_bucketed_launches():
         _assert_matches_solo(r, compiled, app)
     st = eng.stats()
     assert st["launches_by_bucket"] == {1: 1, 2: 1}   # 3 reqs -> 2 + pad(1)
+    assert st["windowed_instead_of_resident"] == 0
+    assert [(m, n) for m, n, _s in eng.launch_walls] == \
+        [("resident", 2), ("resident", 1)]
 
 
 def test_resident_degrades_to_windowed():
@@ -385,6 +391,41 @@ def test_resident_degrades_to_windowed():
     assert eng.supervisor.degraded and eng.mode() == "windowed"
     st = eng.stats()
     assert st["resident_fallbacks"] >= 1 and st["degraded"]
+    assert st["windowed_instead_of_resident"] == 4
     assert [r.status for r in done] == ["ok"] * 4
     for r in done:
         _assert_matches_solo(r, compiled, app)
+
+
+def test_resident_on_backend_without_resident_path_raises():
+    """No silent downgrade: asking for resident launches on the numpy
+    oracle fails at construction, as ``run_fused`` does per call."""
+    app = ALL_APPS["ip2int"]()
+    with pytest.raises(ValueError, match="resident"):
+        AsyncServeEngine(_compiled(app), execution="resident")
+
+
+def test_unsupported_graph_counts_windowed_instead_of_resident():
+    """A graph the resident loop cannot express is served windowed; every
+    such request is counted, so a run that asked for resident can refuse
+    itself without the supervisor ever degrading."""
+    pytest.importorskip("jax")
+    app = ALL_APPS["strlen"]()
+    compiled = _compiled(app, "jax")
+    red_outs = [o for c in compiled.result.dfg.contexts.values()
+                for o in c.outs if o.kind == "reduce"]
+    assert red_outs, "strlen should carry a reduce output"
+    orig = red_outs[0].reduce_op
+    red_outs[0].reduce_op = "xor"      # no resident scatter combiner
+    try:
+        eng = AsyncServeEngine(compiled, execution="resident", max_wave=2)
+        for _ in range(3):
+            eng.submit(_req(app))
+        done = eng.run_until_idle()
+    finally:
+        red_outs[0].reduce_op = orig
+    assert [r.status for r in done] == ["ok"] * 3
+    assert all(r.report.execution == "windowed" for r in done)
+    st = eng.stats()
+    assert st["windowed_instead_of_resident"] == 3
+    assert not st["degraded"] and st["resident_fallbacks"] == 0
