@@ -212,8 +212,10 @@ class RunReport:
     backend: Optional[str]              # executor backend name (vector only)
     wall_s: float                       # the run() call only, no compile
     stats: collections.Counter
-    cycles: int                         # cost-model estimate (vector only)
-    lane_occupancy: float               # useful/issued lanes (vector only)
+    # cost-model estimate and useful/issued lanes: windowed vector runs
+    # only; None for a resident launch, whose loop measures neither
+    cycles: Optional[int]
+    lane_occupancy: Optional[float]
     cache_hit: Optional[bool] = None    # compile-cache outcome of this call
     rid: Optional[int] = None           # request id within a batched launch
     execution: str = "windowed"         # "windowed" | "resident" (§9)
@@ -227,29 +229,36 @@ class RunReport:
         ``CompiledProgram.execute``, ``execute_batch``'s aggregate report,
         and the serving engine's raw-``Prog`` shim, so they cannot drift."""
         is_vec = executor == "vector"
+        execution = getattr(vm, "execution", "windowed")
+        if execution == "resident":
+            cycles, occupancy = None, None
+        elif is_vec:
+            cycles, occupancy = int(vm.estimated_cycles()), vm.lane_occupancy()
+        else:
+            cycles, occupancy = 0, 1.0
         return cls(
             executor=executor,
             backend=vm.backend.name if is_vec else None,
             wall_s=wall_s, stats=vm.stats,
-            cycles=int(vm.estimated_cycles()) if is_vec else 0,
-            lane_occupancy=vm.lane_occupancy() if is_vec else 1.0,
-            cache_hit=cache_hit,
-            execution=getattr(vm, "execution", "windowed"))
+            cycles=cycles, lane_occupancy=occupancy,
+            cache_hit=cache_hit, execution=execution)
 
     @classmethod
     def for_request(cls, vm, rid: int, wall_s: float) -> "RunReport":
         """Per-request view of one batched VectorVM launch: lane-attributable
         stats and cost-model cycles are de-interleaved per request
         (``vm.request_stats``/``request_cycles``); ``wall_s`` is the launch
-        wall amortized over the batch (lane occupancy stays launch-wide)."""
+        wall amortized over the batch (lane occupancy stays launch-wide).
+        A resident launch has neither cycles nor occupancy: both are None."""
+        execution = getattr(vm, "execution", "windowed")
+        resident = execution == "resident"
         return cls(
             executor="vector", backend=vm.backend.name,
             wall_s=wall_s / vm.n_requests,
             stats=vm.request_stats(rid),
-            cycles=vm.request_cycles(rid),
-            lane_occupancy=vm.lane_occupancy(),
-            cache_hit=None, rid=rid,
-            execution=getattr(vm, "execution", "windowed"))
+            cycles=None if resident else vm.request_cycles(rid),
+            lane_occupancy=None if resident else vm.lane_occupancy(),
+            cache_hit=None, rid=rid, execution=execution)
 
 
 @dataclass
@@ -522,10 +531,12 @@ def run_fused(result: CompileResult, backend, requests: Sequence[tuple],
     the aggregate launch stats include them; per-request slices are
     unaffected.  ``"auto"`` selects
     :data:`~repro.core.device_vm.RESIDENT_BUCKETS`."""
+    from jax.profiler import TraceAnnotation
     inits = [arrays for arrays, _scalars in requests]
     params = [{k: int(v) for k, v in scalars.items()}
               for _arrays, scalars in requests]
     nreq = len(requests)
+    slots = nreq
     resident_fallback = None
     resident_ok = False
     if execution not in ("windowed", "resident"):
@@ -543,17 +554,18 @@ def run_fused(result: CompileResult, backend, requests: Sequence[tuple],
         if not reasons:
             resident_ok = True
             if bucket_sizes:
-                b = bucket_launch_size(nreq, bucket_sizes)
-                if b > nreq:
-                    inits = list(inits) + [inits[-1]] * (b - nreq)
-                    params = list(params) + [params[-1]] * (b - nreq)
-                    nreq = b
+                slots = bucket_launch_size(nreq, bucket_sizes)
         else:
             resident_fallback = "; ".join(reasons)
+    with TraceAnnotation("revet.batch.fuse", slots=slots):
+        if slots > nreq:
+            inits = list(inits) + [inits[-1]] * (slots - nreq)
+            params = list(params) + [params[-1]] * (slots - nreq)
+            nreq = slots
+        fused = fuse_dram_images(result.dfg, inits)
     pool_override = dict(vm_kwargs.pop("pool_override", None) or {})
     for pname, pool in result.dfg.pools.items():
         pool_override.setdefault(pname, pool.n_bufs * nreq)
-    fused = fuse_dram_images(result.dfg, inits)
     if resident_ok:
         vm_kwargs.pop("queue_cap", None)   # host knob; rings size
         dp = _resident_program(result, be, nreq, pool_override,
@@ -819,12 +831,14 @@ class CompiledProgram:
         ``execution`` overrides the compiled ``CompileOptions.execution``
         mode: ``"resident"`` serves the whole batch as one fused device
         launch (DESIGN.md §9; replicas do not apply there)."""
+        from jax.profiler import TraceAnnotation
         reqs = [(dict(a or {}), dict(s or {})) for a, s in requests]
         if not reqs:
             raise ValueError(f"{self.name}: execute_batch needs at least "
                              "one request")
-        for arrays, scalars in reqs:
-            self._check_request(arrays, scalars, require_inputs)
+        with TraceAnnotation("revet.batch.check", requests=len(reqs)):
+            for arrays, scalars in reqs:
+                self._check_request(arrays, scalars, require_inputs)
         r = self.default_replicas() if replicas is None else int(replicas)
         mode = execution if execution is not None else \
             getattr(self.result.options, "execution", "windowed")
@@ -833,15 +847,16 @@ class CompiledProgram:
             reqs, replicas=r, placement=self.placement, execution=mode,
             **vm_kwargs)
         executions = []
-        for rid in range(len(reqs)):
-            dram = vm.request_dram(rid)
-            # outputs are copies (not views of dram) so in-place mutation
-            # behaves exactly like the solo execute path
-            outputs = tuple(np.asarray(dram[n]).copy()
-                            for n, _sz, _dt in self.out_info)
-            executions.append(Execution(
-                outputs, dram, RunReport.for_request(vm, rid, wall),
-                vm, self))
+        with TraceAnnotation("revet.batch.split"):
+            for rid in range(len(reqs)):
+                dram = vm.request_dram(rid)
+                # outputs are copies (not views of dram) so in-place
+                # mutation behaves exactly like the solo execute path
+                outputs = tuple(np.asarray(dram[n]).copy()
+                                for n, _sz, _dt in self.out_info)
+                executions.append(Execution(
+                    outputs, dram, RunReport.for_request(vm, rid, wall),
+                    vm, self))
         return BatchExecution(tuple(executions), vm,
                               RunReport.from_vm(vm, "vector", wall))
 

@@ -998,8 +998,12 @@ class DeviceProgram:
             # the firing wavefront, not the whole graph.  Each cond carries
             # only the keys its context writes — read-only state (DRAM
             # images, other rings) is closed over, never copied through.
+            # The named scopes reach the HLO's op_name metadata, so a
+            # device trace attributes loop time to each context; context
+            # names repeat ("foreach", "if.then"), the id keeps them apart.
             st = dict(st)
-            rdy = ready_of(st)
+            with jax.named_scope("revet.ready"):
+                rdy = ready_of(st)
             st["prog"] = jnp.zeros((), bool)
             for ctx in self.order:
                 wkeys = write_set(ctx, st)
@@ -1013,19 +1017,26 @@ class DeviceProgram:
                     s["prog"] = s["prog"] | f
                     return {k: s[k] for k in wkeys}
 
-                st.update(jax.lax.cond(rdy[ctx.id], taken,
-                                       lambda s: dict(s), sub))
+                with jax.named_scope(f"revet.ctx.{ctx.name}.{ctx.id}"):
+                    st.update(jax.lax.cond(rdy[ctx.id], taken,
+                                           lambda s: dict(s), sub))
             st["tick"] = st["tick"] + 1
             stat_add(st, "ticks", 1)
             return st
+
+        def body(st):
+            with jax.named_scope("revet.loop"):
+                return tick(st)
 
         def cond(st):
             return st["prog"] & (st["err"] == 0) & \
                 (st["tick"] < self.max_ticks)
 
         def run(st):
-            return jax.lax.while_loop(cond, tick, st)
+            return jax.lax.while_loop(cond, body, st)
 
+        # the function's name fixes the XLA module's, ``jit_run``: the
+        # benchmark's loop_ms reads that module from the device trace
         self._jit_run = jax.jit(run)
         self._tick = tick           # uncompiled tick body, for diagnostics
 
@@ -1036,13 +1047,18 @@ class DeviceProgram:
     def run_batch(self, params_list: list[dict],
                   dram_init=None) -> "DeviceRun":
         """One launch: init state, run the jitted while-loop to quiescence,
-        decode errors, unpack DRAM + stats."""
+        decode errors, unpack DRAM + stats.  Each stage is a profiler span
+        (DESIGN.md §10, "Tracing a serving process")."""
         import jax
+        from jax.profiler import TraceAnnotation
         if self._jit_run is None:
             self._build()
-        st = self._init_state(dram_init, params_list)
-        out = jax.block_until_ready(self._jit_run(st))
-        return self._finish(out)
+        with TraceAnnotation("revet.launch.upload"):
+            st = self._init_state(dram_init, params_list)
+        with TraceAnnotation("revet.launch.loop"):
+            out = jax.block_until_ready(self._jit_run(st))
+        with TraceAnnotation("revet.launch.readback"):
+            return self._finish(out)
 
     def _finish(self, out) -> "DeviceRun":
         err = int(out["err"])
@@ -1131,17 +1147,6 @@ class DeviceRun:
         self._dram_lim = dram_lim
         self.backend = backend if backend is not None \
             else _BackendTag("jax[resident]")
-
-    def estimated_cycles(self) -> int:
-        """Cost-model cycles are a windowed-scheduler artifact (per-window
-        occupancy); the resident loop does not reconstruct them."""
-        return 0
-
-    def lane_occupancy(self) -> float:
-        return 1.0
-
-    def request_cycles(self, rid: int) -> int:
-        return 0
 
     def request_dram(self, rid: int) -> dict[str, np.ndarray]:
         if not 0 <= rid < self.n_requests:

@@ -45,6 +45,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..api import CompiledProgram, RunReport, WaveSession
 from ..core.backend import ExecutorBackend, make_backend
@@ -174,6 +175,9 @@ class AsyncServeEngine:
         self.warmup_launches = 0
         self.warmup_s = {"resident": 0.0, "windowed": 0.0}  # wall, per mode
         self.launch_walls: list[tuple[str, int, float]] = []  # mode, size, s
+        # one per launch_walls entry: the launch report's ticks (device loop
+        # iterations for a resident launch, supersteps for a windowed wave)
+        self.launch_ticks: list[int] = []
 
     # ------------------------------------------------------------ admission
     @property
@@ -331,7 +335,8 @@ class AsyncServeEngine:
         launch.  Returns the responses that completed this quantum."""
         if self.mode() == "resident":
             return self._pump_resident()
-        return self._pump_windowed()
+        with TraceAnnotation("revet.pump", launch=len(self.launch_walls)):
+            return self._pump_windowed()
 
     def run_until_idle(self, max_wall_s: Optional[float] = None,
                        ) -> list[AsyncResponse]:
@@ -436,12 +441,21 @@ class AsyncServeEngine:
             return [self._resolve_failed(r, e) for r in reqs]
         self.launch_counts[len(reqs)] += 1
         self.launch_walls.append(("windowed", len(reqs), bx.report.wall_s))
+        self.launch_ticks.append(int(bx.report.stats["ticks"]))
         return [self._resolve_ok(r, ex) for r, ex in zip(reqs, bx)]
 
     # resident: closed bucketed launches ------------------------------------
     def _pump_resident(self) -> list[AsyncResponse]:
         if not self.queue_depth:
             return []
+        served = min(self.max_wave, self.queue_depth)
+        size = served if not self.bucket_sizes else \
+            bucket_launch_size(served, self.bucket_sizes)
+        with TraceAnnotation("revet.pump", launch=len(self.launch_walls),
+                             size=size, served=served):
+            return self._launch_resident(size)
+
+    def _launch_resident(self, size: int) -> list[AsyncResponse]:
         batch: list[AsyncRequest] = []
         while len(batch) < self.max_wave and self.queue_depth:
             batch.append(self._admit_pop())
@@ -477,12 +491,12 @@ class AsyncServeEngine:
                     "resident", "launch retries exhausted; degrading")
                 self.supervisor.degraded = True
             return out
-        size = len(reqs) if not self.bucket_sizes else \
-            bucket_launch_size(len(reqs), self.bucket_sizes)
         self.launch_counts[size] += 1
         self.launch_walls.append((bx.report.execution, size,
                                   bx.report.wall_s))
-        return [self._resolve_ok(r, ex) for r, ex in zip(batch, bx)]
+        self.launch_ticks.append(int(bx.report.stats["ticks"]))
+        with TraceAnnotation("revet.pump.resolve"):
+            return [self._resolve_ok(r, ex) for r, ex in zip(batch, bx)]
 
     # --------------------------------------------------------------- warmup
     def warmup(self, arrays: Optional[dict] = None,
@@ -555,6 +569,7 @@ class AsyncServeEngine:
             "time_in_queue_mean_s": (self.queue_s_total / served
                                      if served else 0.0),
             "launches": sum(self.launch_counts.values()),
+            "ticks": sum(self.launch_ticks),
             "launches_by_bucket": dict(sorted(self.launch_counts.items())),
             "warmup_launches": self.warmup_launches,
             "warmup_s": dict(self.warmup_s),

@@ -57,7 +57,8 @@ class DataflowResponse:
         return self.report.stats
 
     @property
-    def cycles(self) -> int:
+    def cycles(self) -> Optional[int]:
+        """Cost-model cycles; None for a resident launch."""
         return self.report.cycles
 
     @property

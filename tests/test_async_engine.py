@@ -347,10 +347,17 @@ def test_async_stats_keys_complete():
 # resident mode: bucketed launches + degraded fallback (jax only)
 # ---------------------------------------------------------------------------
 
-def test_resident_async_bucketed_launches():
+@pytest.fixture(scope="module")
+def ip2int_jax():
+    """One jax compile of ip2int, shared by the resident tests below so that
+    each launch shape's DeviceProgram compiles once."""
     pytest.importorskip("jax")
     app = ALL_APPS["ip2int"]()
-    compiled = _compiled(app, "jax")
+    return app, _compiled(app, "jax")
+
+
+def test_resident_async_bucketed_launches(ip2int_jax):
+    app, compiled = ip2int_jax
     eng = AsyncServeEngine(compiled, backend="jax", execution="resident",
                            max_wave=2, queue_cap=8)
     assert eng.mode() == "resident"
@@ -429,3 +436,91 @@ def test_unsupported_graph_counts_windowed_instead_of_resident():
     st = eng.stats()
     assert st["windowed_instead_of_resident"] == 3
     assert not st["degraded"] and st["resident_fallbacks"] == 0
+
+
+# ---------------------------------------------------------------------------
+# resident launch path: tick counter and profiler spans
+# ---------------------------------------------------------------------------
+
+def test_resident_launch_ticks_counted(ip2int_jax):
+    """Each resident launch keeps the ticks of its launch report (the
+    device loop's iterations), and stats() sums them."""
+    app, compiled = ip2int_jax
+    eng = AsyncServeEngine(compiled, backend="jax", execution="resident",
+                           max_wave=2, queue_cap=8)
+    for _ in range(3):
+        eng.submit(_req(app))
+    done = eng.run_until_idle()
+    assert [r.status for r in done] == ["ok"] * 3
+    want = [int(compiled.execute_batch(
+        [(dict(app.dram_init), dict(app.params))] * n,
+        require_inputs=False, execution="resident",
+        bucket_sizes="auto").report.stats["ticks"]) for n in (2, 1)]
+    assert all(t > 0 for t in want)
+    assert eng.launch_ticks == want
+    assert eng.stats()["ticks"] == sum(want)
+    # a resident report claims no cost-model cycles or lane occupancy
+    for r in done:
+        assert r.report.cycles is None and r.report.lane_occupancy is None
+
+
+def test_windowed_wave_ticks_counted():
+    app = ALL_APPS["ip2int"]()
+    eng = AsyncServeEngine(_compiled(app), max_wave=2)
+    for _ in range(2):
+        eng.submit(_req(app))
+    done = eng.run_until_idle()
+    assert [r.status for r in done] == ["ok"] * 2
+    assert len(eng.launch_ticks) == len(eng.launch_walls) == 1
+    assert eng.stats()["ticks"] == eng.launch_ticks[0] > 0
+    # windowed reports keep their cost-model numbers
+    assert all(r.report.cycles > 0 for r in done)
+
+
+LAUNCH_SPANS = ("revet.batch.check", "revet.batch.fuse",
+                "revet.launch.upload", "revet.launch.loop",
+                "revet.launch.readback", "revet.batch.split",
+                "revet.pump.resolve")
+
+
+def test_resident_pump_spans_in_profile(ip2int_jax, tmp_path):
+    """A profiler capture of one resident pump holds the launch path's
+    eight spans: ``revet.pump`` with its arguments, and the seven stages
+    inside it, once each and in order."""
+    import jax
+    from jax.profiler import ProfileData
+    app, compiled = ip2int_jax
+    eng = AsyncServeEngine(compiled, backend="jax", execution="resident",
+                           max_wave=2, queue_cap=8, bucket_sizes=(2,))
+    for _ in range(3):
+        eng.submit(_req(app))
+    eng.pump()                      # launch 0, untraced
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        done = eng.pump()           # launch 1: 1 request in a bucket of 2
+    finally:
+        jax.profiler.stop_trace()
+    assert [r.status for r in done] == ["ok"]
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("revet."):
+                    assert e.name not in spans, e.name
+                    spans[e.name] = (e.start_ns, e.end_ns, dict(e.stats))
+    assert set(spans) == {"revet.pump", *LAUNCH_SPANS}
+    p0, p1, args = spans["revet.pump"]
+    assert args == {"launch": 1, "size": 2, "served": 1}
+    assert spans["revet.batch.check"][2] == {"requests": 1}
+    assert spans["revet.batch.fuse"][2] == {"slots": 2}
+    starts = [spans[n][0] for n in LAUNCH_SPANS]
+    assert starts == sorted(starts)
+    for n in LAUNCH_SPANS:
+        s, e, _ = spans[n]
+        assert p0 <= s <= e <= p1, n
+    # the stages do not overlap
+    for a, b in zip(LAUNCH_SPANS, LAUNCH_SPANS[1:]):
+        assert spans[a][1] <= spans[b][0], (a, b)

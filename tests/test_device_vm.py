@@ -7,6 +7,8 @@ capacity pre-check and :class:`QueueOverflow` diagnostics, the
 placement-derived ring sizing, and the windowed fallback for graphs the
 fused loop cannot express.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -198,3 +200,26 @@ def test_resident_on_numpy_backend_raises():
     with pytest.raises(ValueError, match="resident"):
         run_fused(res, "numpy", [(dict(app.dram_init), dict(app.params))],
                   execution="resident")
+
+
+# ---------------------------------------------------------------------------
+# names a device trace reads
+# ---------------------------------------------------------------------------
+
+def test_loop_module_name_and_scopes():
+    """The resident loop compiles to the XLA module ``jit_run`` (the
+    benchmark's ``loop_ms`` finds it by that prefix), and the program's
+    named scopes reach its HLO metadata: ``revet.loop`` around the tick,
+    ``revet.ready`` around the ready snapshot, and one
+    ``revet.ctx.<name>.<id>`` around each context's cond."""
+    app, g = _dfg()
+    dp = DeviceProgram(g)
+    dp._build()
+    st = dp._init_state(dict(app.dram_init), [dict(app.params)])
+    text = dp._jit_run.lower(st).compile().as_text()
+    assert text.startswith("HloModule jit_run")
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any("/revet.loop/revet.ready/" in n for n in names)
+    for c in g.contexts.values():
+        assert any(f"/revet.loop/revet.ctx.{c.name}.{c.id}/cond" in n
+                   for n in names), c.name
