@@ -441,12 +441,18 @@ class WaveSession:
                               RunReport.from_vm(vm, "vector", self.wall_s))
 
 
-def fuse_dram_images(dfg, inits: Sequence[dict]) -> dict[str, np.ndarray]:
+def fuse_dram_images(dfg, inits: Sequence[dict],
+                     shared=frozenset()) -> dict[str, np.ndarray]:
     """Concatenate per-request DRAM init images into one fused image:
     request ``r``'s values land at base offset ``r * size`` of each array
     (the layout :meth:`~repro.core.vector_vm.VectorVM.request_dram` splits
     back apart). Requests may omit arrays — their slice stays zero, exactly
-    like a single-request run without that init."""
+    like a single-request run without that init.
+
+    Each array named in ``shared`` (a resident launch's
+    :func:`~repro.core.device_vm.shared_dram`, equal in every request) is
+    laid out once: the fused image holds the first request's value as it
+    is, uncopied.  The windowed executors pass none."""
     fused: dict[str, np.ndarray] = {}
     nreq = len(inits)
     for r, init in enumerate(inits):
@@ -459,6 +465,14 @@ def fuse_dram_images(dfg, inits: Sequence[dict]) -> dict[str, np.ndarray]:
                 f"(declared: {sorted(dfg.dram)})")
     for name, d in dfg.dram.items():
         if not any(name in init for init in inits):
+            continue
+        if name in shared:
+            a = np.ravel(inits[0][name])
+            if a.size > d.size:
+                raise ValueError(
+                    f"request 0: init for '{name}' has {a.size} elements, "
+                    f"DRAM array holds {d.size}")
+            fused[name] = a
             continue
         buf = np.zeros(d.size * nreq, np.int64)
         for r, init in enumerate(inits):
@@ -477,24 +491,31 @@ def fuse_dram_images(dfg, inits: Sequence[dict]) -> dict[str, np.ndarray]:
 
 
 def _resident_program(result: CompileResult, backend, n_requests: int,
-                      pool_override: dict, placement, **dp_kwargs):
+                      pool_override: dict, placement, shared=frozenset(),
+                      **dp_kwargs):
     """The per-launch-shape :class:`~repro.core.device_vm.DeviceProgram`
-    cache: one jit trace per ``(n_requests, pools, ring caps)`` shape for
-    the lifetime of the ``CompileResult`` — the resident analogue of the
-    windowed path's per-window kernel cache, with one entry per *program*.
-    """
+    cache: one jit trace per ``(n_requests, pools, ring caps, shared
+    arrays)`` shape for the lifetime of the ``CompileResult`` — the
+    resident analogue of the windowed path's per-window kernel cache, with
+    one entry per *program*.  Every shape shares one
+    :class:`~repro.core.device_vm.SharedDram`, so a shared array stays on
+    the device across launches of any bucket."""
+    from .core.device_vm import SharedDram
     cache = getattr(result, "_resident_cache", None)
     if cache is None:
         cache = result._resident_cache = {}
+        result._shared_dram = SharedDram()
     key = (n_requests,
            tuple(sorted(pool_override.items())),
            tuple(sorted((dp_kwargs.get("queue_caps") or {}).items())),
-           dp_kwargs.get("max_ticks"))
+           dp_kwargs.get("max_ticks"),
+           tuple(sorted(shared)))
     dp = cache.get(key)
     if dp is None:
         dp = cache[key] = backend.compile_resident(
             result, placement=placement, n_requests=n_requests,
-            pool_override=pool_override,
+            pool_override=pool_override, shared=shared,
+            shared_store=result._shared_dram,
             **{k: v for k, v in dp_kwargs.items() if v is not None})
     return dp
 
@@ -549,7 +570,8 @@ def run_fused(result: CompileResult, backend, requests: Sequence[tuple],
                 f"execution='resident': backend {be.name!r} has no "
                 "resident path (the numpy oracle stays windowed; use "
                 "backend='jax')")
-        from .core.device_vm import bucket_launch_size, resident_unsupported
+        from .core.device_vm import (bucket_launch_size,
+                                     resident_unsupported, shared_dram)
         reasons = resident_unsupported(result.dfg)
         if not reasons:
             resident_ok = True
@@ -557,19 +579,22 @@ def run_fused(result: CompileResult, backend, requests: Sequence[tuple],
                 slots = bucket_launch_size(nreq, bucket_sizes)
         else:
             resident_fallback = "; ".join(reasons)
+    shared = frozenset()
     with TraceAnnotation("revet.batch.fuse", slots=slots):
         if slots > nreq:
             inits = list(inits) + [inits[-1]] * (slots - nreq)
             params = list(params) + [params[-1]] * (slots - nreq)
             nreq = slots
-        fused = fuse_dram_images(result.dfg, inits)
+        if resident_ok:
+            shared = shared_dram(result.dfg, inits)
+        fused = fuse_dram_images(result.dfg, inits, shared)
     pool_override = dict(vm_kwargs.pop("pool_override", None) or {})
     for pname, pool in result.dfg.pools.items():
         pool_override.setdefault(pname, pool.n_bufs * nreq)
     if resident_ok:
         vm_kwargs.pop("queue_cap", None)   # host knob; rings size
         dp = _resident_program(result, be, nreq, pool_override,
-                               placement, **vm_kwargs)
+                               placement, shared, **vm_kwargs)
         t0 = time.perf_counter()
         run = dp.run_batch(params, fused)
         return run, time.perf_counter() - t0

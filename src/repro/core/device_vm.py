@@ -146,24 +146,130 @@ def queue_capacities(g: DFG, placement=None, vlen: int = VLEN
 _DTYPE_MASK = {"i8": 0xFF, "i16": 0xFFFF, "i32": None}
 
 
+def written_dram(g: DFG) -> frozenset:
+    """DRAM arrays some ``dram_store`` or ``atomic_add`` of the graph
+    writes (read once per graph, cached on it)."""
+    w = getattr(g, "_written_dram", None)
+    if w is None:
+        w = g._written_dram = frozenset(
+            op.space for c in g.contexts.values() for op in c.body
+            if op.op in ("dram_store", "atomic_add"))
+    return w
+
+
+def shared_dram(g: DFG, inits) -> frozenset:
+    """The arrays a resident launch lays out once instead of once per
+    request (DESIGN.md §9): the program never writes them, and every
+    request of the launch carries the same value — absent from all (all
+    zeros) or present with equal contents."""
+    written = written_dram(g)
+    shared = []
+    for name in g.dram:
+        if name in written:
+            continue
+        vals = [init.get(name) for init in inits]
+        first = vals[0]
+        if first is None:
+            same = all(v is None for v in vals)
+        else:
+            flat = np.ravel(first)
+            same = all(v is first or (v is not None and np.array_equal(
+                np.ravel(v), flat)) for v in vals)
+        if same:
+            shared.append(name)
+    return frozenset(shared)
+
+
+class _SharedArray:
+    """One shared array: its device buffer, the int32 host image it was
+    uploaded from (``d.size`` words; a launch's value is compared with
+    it), and that image as requests read it back (int64, read-only)."""
+
+    def __init__(self, host: np.ndarray, n: Optional[int], dev):
+        self.host = host
+        self.n = n              # words the caller gave; None when absent
+        self.dev = dev
+        self.view = host.astype(np.int64)
+        self.view.setflags(write=False)
+
+    def matches(self, value) -> bool:
+        if value is None:
+            return self.n is None
+        v = np.ravel(value)
+        return self.n == v.size and np.array_equal(v, self.host[: v.size])
+
+
+# One launch's host-to-device account: the shared arrays it read (name ->
+# _SharedArray), the bytes it copied from the host, and how many shared
+# arrays it had to make anew instead of reusing.
+_Upload = collections.namedtuple("_Upload", "arrays sent fresh")
+
+
+class SharedDram:
+    """Device buffers of the shared arrays, at most one per array name,
+    kept across launches by every :class:`DeviceProgram` of one compiled
+    program.  A buffer is reused while the launch's value equals the host
+    image it was uploaded from; the check is one compare per array per
+    launch and is never skipped, so a caller that mutates the array in
+    place between launches gets the new contents."""
+
+    def __init__(self):
+        self._arrays: dict[str, _SharedArray] = {}
+
+    def get(self, name: str, dtype: str, size: int, value
+            ) -> tuple[_SharedArray, bool]:
+        """The resident buffer for ``value`` (None: absent, all zeros),
+        made anew when it differs from the one held; returns the entry and
+        whether it was made anew.  An absent value is zeroed on the device
+        and copies nothing from the host."""
+        import jax.numpy as jnp
+        from .backend import wrap_dram_init
+        e = self._arrays.get(name)
+        if e is not None and e.matches(value):
+            return e, False
+        self._arrays.pop(name, None)    # free the old buffer first
+        if value is None:
+            e = _SharedArray(np.zeros(size, np.int32), None,
+                             jnp.zeros(size, jnp.int32))
+        else:
+            w = wrap_dram_init(value, dtype)
+            host = np.zeros(size, np.int32)
+            host[: w.size] = w
+            e = _SharedArray(host, w.size, jnp.asarray(host))
+        self._arrays[name] = e
+        return e, True
+
+
 class DeviceProgram:
     """One DFG compiled to a single resident device launch.
 
-    Specialized per ``(n_requests, vlen, queue capacities, pool sizes)`` —
-    the front-end caches instances per shape (``CompiledProgram``), so a
-    serving deployment jit-compiles once per launch shape, exactly like
-    the windowed jax path's per-window kernel cache but with *one* cache
-    entry for the whole program.
+    Specialized per ``(n_requests, vlen, queue capacities, pool sizes,
+    shared arrays)`` — the front-end caches instances per shape
+    (``CompiledProgram``), so a serving deployment jit-compiles once per
+    launch shape, exactly like the windowed jax path's per-window kernel
+    cache but with *one* cache entry for the whole program.
+
+    ``shared`` names the arrays laid out once for the whole launch
+    (:func:`shared_dram`); their buffers live in ``shared_store``, which
+    the programs of every launch shape share.
     """
 
     def __init__(self, g: DFG, *, n_requests: int = 1, vlen: int = VLEN,
                  queue_caps: dict[int, int] | None = None, placement=None,
                  pool_override: dict[str, int] | None = None,
-                 max_ticks: int = 1_000_000):
+                 max_ticks: int = 1_000_000, shared=frozenset(),
+                 shared_store: SharedDram | None = None):
         reasons = resident_unsupported(g)
         if reasons:
             raise VectorDeadlock(
                 "resident execution unsupported: " + "; ".join(reasons))
+        self.shared = frozenset(shared)
+        bad = sorted(self.shared - (set(g.dram) - written_dram(g)))
+        if bad:
+            raise ValueError(f"arrays {bad} cannot be shared: the program "
+                             f"writes them or does not declare them")
+        self.shared_store = shared_store if shared_store is not None \
+            else SharedDram()
         self.g = g
         self.vlen = int(vlen)
         self.n_requests = int(n_requests)
@@ -228,7 +334,12 @@ class DeviceProgram:
 
     # ------------------------------------------------------------ host state
     def _init_state(self, dram_init: dict[str, np.ndarray] | None,
-                    params_list: list[dict]) -> dict:
+                    params_list: list[dict]):
+        """Device state of one launch: the loop carry; the shared arrays'
+        resident buffers, keyed like the carry's DRAM entries and passed to
+        the loop outside it; and the launch's :class:`_Upload` account.
+        ``dram_init`` holds the fused per-request images and, for each
+        shared array, the one value every request carries."""
         import jax.numpy as jnp
         from .backend import wrap_dram_init
         g = self.g
@@ -236,6 +347,14 @@ class DeviceProgram:
             raise ValueError(
                 f"run_batch: got {len(params_list)} parameter sets for a "
                 f"device program with n_requests={self.n_requests}")
+        dram_init = dram_init or {}
+        sent = fresh = 0
+
+        def put(a: np.ndarray):
+            nonlocal sent
+            sent += a.nbytes
+            return jnp.asarray(a)
+
         st: dict = {}
         n_rings = len(self.lids) + 1
         pad = 2 * self.vlen           # scratch pad: widest push is 2W (reduce)
@@ -257,16 +376,26 @@ class DeviceProgram:
             sv[r, -1] = r
         sk[self.n_requests] = 1
         qt[self.src_row] = self.n_requests + 1
-        st["qkS"] = jnp.asarray(sk)
-        st["qvS"] = jnp.asarray(sv)
-        st["qh"], st["qt"] = jnp.asarray(qh), jnp.asarray(qt)
+        st["qkS"] = put(sk)
+        st["qvS"] = put(sv)
+        st["qh"], st["qt"] = put(qh), put(qt)
         st["lt"] = jnp.zeros(len(self.lids), jnp.int32)
+        shared: dict = {}
+        arrays: dict = {}
         for name, d in g.dram.items():
+            if name in self.shared:
+                e, made = self.shared_store.get(name, d.dtype, d.size,
+                                                dram_init.get(name))
+                arrays[name], shared[f"d_{name}"] = e, e.dev
+                fresh += made
+                if made and e.n is not None:
+                    sent += e.host.nbytes
+                continue
             a = np.zeros(d.size * self.n_requests, np.int32)
-            if dram_init and name in dram_init:
+            if name in dram_init:
                 w = wrap_dram_init(dram_init[name], d.dtype)
                 a[: w.size] = w.astype(np.int32)
-            st[f"d_{name}"] = jnp.asarray(a)
+            st[f"d_{name}"] = put(a)
         n_pools = len(self.pool_names)
         st["fh"] = jnp.zeros(max(n_pools, 1), jnp.int32)
         ft = np.zeros(max(n_pools, 1), np.int32)
@@ -274,10 +403,10 @@ class DeviceProgram:
             nb, bw = self.pool_bufs[p], self.pool_words[p]
             st[f"p_{p}"] = jnp.zeros(nb * bw, jnp.int32)
             flcap = _next_pow2(nb)
-            st[f"fr_{p}"] = jnp.asarray(
+            st[f"fr_{p}"] = put(
                 np.resize(np.arange(nb, dtype=np.int32), flcap))
             ft[self.pool_row[p]] = nb
-        st["ft"] = jnp.asarray(ft)
+        st["ft"] = put(ft)
         n_cnt = max(len(self.cnt_ctxs), 1)
         st["cnt_act"] = jnp.zeros(n_cnt, bool)
         for key in ("cnt_cur", "cnt_hi", "cnt_step"):
@@ -298,13 +427,13 @@ class DeviceProgram:
         racc = np.zeros(n_red, np.int32)
         for (cid, oi), i in self.red_row.items():
             racc[i] = ir.wrap32(g.contexts[cid].outs[oi].reduce_init)
-        st["red_acc"] = jnp.asarray(racc)
+        st["red_acc"] = put(racc)
         st["red_open"] = jnp.zeros(n_red, bool)
         st["stats"] = jnp.zeros(len(self._stat_keys), jnp.int32)
         st["prog"] = jnp.asarray(True)
         st["err"] = jnp.zeros((), jnp.int32)
         st["tick"] = jnp.zeros((), jnp.int32)
-        return st
+        return st, shared, _Upload(arrays, sent, fresh)
 
     # ------------------------------------------------------------- jit build
     def _build(self) -> None:
@@ -439,7 +568,8 @@ class DeviceProgram:
                     lim = self._dram_lim[op.space]
                     addr = regs[op.srcs[0]]
                     ok = data & (addr >= 0) & (addr < lim)
-                    if batched:
+                    # a shared array is laid out once: no per-request base
+                    if batched and op.space not in self.shared:
                         addr = addr + rid * I32(lim)
                     regs[op.dst] = jnp.where(ok, a[jnp.where(ok, addr, 0)], 0)
                     stat_add(st, "dram_reads", ok.sum())
@@ -1024,15 +1154,18 @@ class DeviceProgram:
             stat_add(st, "ticks", 1)
             return st
 
-        def body(st):
-            with jax.named_scope("revet.loop"):
-                return tick(st)
-
         def cond(st):
             return st["prog"] & (st["err"] == 0) & \
                 (st["tick"] < self.max_ticks)
 
-        def run(st):
+        def run(st, shared):
+            # the shared arrays are operands outside the carry: the loop
+            # reads them like any DRAM array, never copies or returns them
+            def body(st):
+                with jax.named_scope("revet.loop"):
+                    out = tick({**st, **shared})
+                return {k: out[k] for k in st}
+
             return jax.lax.while_loop(cond, body, st)
 
         # the function's name fixes the XLA module's, ``jit_run``: the
@@ -1053,14 +1186,17 @@ class DeviceProgram:
         from jax.profiler import TraceAnnotation
         if self._jit_run is None:
             self._build()
-        with TraceAnnotation("revet.launch.upload"):
-            st = self._init_state(dram_init, params_list)
+        with TraceAnnotation("revet.launch.upload") as span:
+            st, shared, up = self._init_state(dram_init, params_list)
+            span.set_metadata(shared=len(self.shared),
+                              reused=int(bool(self.shared) and not up.fresh),
+                              upload_mib=up.sent / 2 ** 20)
         with TraceAnnotation("revet.launch.loop"):
-            out = jax.block_until_ready(self._jit_run(st))
+            out = jax.block_until_ready(self._jit_run(st, shared))
         with TraceAnnotation("revet.launch.readback"):
-            return self._finish(out)
+            return self._finish(out, up)
 
-    def _finish(self, out) -> "DeviceRun":
+    def _finish(self, out, up: _Upload) -> "DeviceRun":
         err = int(out["err"])
         if err:
             self._raise_err(err)
@@ -1073,13 +1209,16 @@ class DeviceProgram:
         if stuck:
             raise VectorDeadlock(
                 f"quiescent with tokens in flight: {stuck}")
-        dram = {name: np.asarray(out[f"d_{name}"]).astype(np.int64)
+        dram = {name: (up.arrays[name].view if name in self.shared else
+                       np.asarray(out[f"d_{name}"]).astype(np.int64))
                 for name in self.g.dram}
         stats = collections.Counter()
         sv = np.asarray(out["stats"])
         for k, i in self._stat_row.items():
             if sv[i]:
                 stats[k] = int(sv[i])
+        if self.shared:
+            stats["shared_uploads" if up.fresh else "shared_reuses"] = 1
         lt = np.asarray(out["lt"])
         for lid in self.lids:
             if lt[self.row_of[lid]]:
@@ -1087,7 +1226,7 @@ class DeviceProgram:
         return DeviceRun(dram=dram, stats=stats,
                          n_requests=self.n_requests,
                          dram_lim=dict(self._dram_lim),
-                         backend=self.backend)
+                         backend=self.backend, shared=self.shared)
 
     def _raise_err(self, err: int) -> None:
         n_rings = len(self.lids) + 1
@@ -1140,19 +1279,25 @@ class DeviceRun:
     launches = 1
     execution = "resident"
 
-    def __init__(self, dram, stats, n_requests, dram_lim, backend=None):
+    def __init__(self, dram, stats, n_requests, dram_lim, backend=None,
+                 shared=frozenset()):
         self.dram = dram
         self.stats = stats
         self.n_requests = n_requests
         self._dram_lim = dram_lim
+        self.shared = frozenset(shared)
         self.backend = backend if backend is not None \
             else _BackendTag("jax[resident]")
 
     def request_dram(self, rid: int) -> dict[str, np.ndarray]:
+        """One request's DRAM image: a copy of its slice of each
+        per-request array, and the read-only array itself for each shared
+        one (every request holds the same values there)."""
         if not 0 <= rid < self.n_requests:
             raise IndexError(f"request id {rid} out of range "
                              f"[0, {self.n_requests})")
-        return {name: self.dram[name][rid * sz: (rid + 1) * sz].copy()
+        return {name: (self.dram[name] if name in self.shared else
+                       self.dram[name][rid * sz: (rid + 1) * sz].copy())
                 for name, sz in self._dram_lim.items()}
 
     def request_stats(self, rid: int) -> collections.Counter:

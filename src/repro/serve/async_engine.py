@@ -495,6 +495,9 @@ class AsyncServeEngine:
         self.launch_walls.append((bx.report.execution, size,
                                   bx.report.wall_s))
         self.launch_ticks.append(int(bx.report.stats["ticks"]))
+        # launches that made a shared array anew / found all of them resident
+        for k in ("shared_uploads", "shared_reuses"):
+            self.counters[k] += int(bx.report.stats.get(k, 0))
         with TraceAnnotation("revet.pump.resolve"):
             return [self._resolve_ok(r, ex) for r, ex in zip(batch, bx)]
 
@@ -570,6 +573,8 @@ class AsyncServeEngine:
                                      if served else 0.0),
             "launches": sum(self.launch_counts.values()),
             "ticks": sum(self.launch_ticks),
+            "shared_uploads": int(self.counters["shared_uploads"]),
+            "shared_reuses": int(self.counters["shared_reuses"]),
             "launches_by_bucket": dict(sorted(self.launch_counts.items())),
             "warmup_launches": self.warmup_launches,
             "warmup_s": dict(self.warmup_s),

@@ -516,6 +516,12 @@ def test_resident_pump_spans_in_profile(ip2int_jax, tmp_path):
     assert args == {"launch": 1, "size": 2, "served": 1}
     assert spans["revet.batch.check"][2] == {"requests": 1}
     assert spans["revet.batch.fuse"][2] == {"slots": 2}
+    # the upload counts the shared read-only arrays; launch 0 left them
+    # resident, so this launch copies only the per-request words
+    up = spans["revet.launch.upload"][2]
+    assert set(up) == {"shared", "reused", "upload_mib"}
+    assert up["shared"] >= 1 and up["reused"] == 1
+    assert 0 < up["upload_mib"] < 1
     starts = [spans[n][0] for n in LAUNCH_SPANS]
     assert starts == sorted(starts)
     for n in LAUNCH_SPANS:

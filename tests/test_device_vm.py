@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from repro.apps import ALL_APPS
 from repro.core.compiler import CompileOptions, compile_program
 from repro.core.device_vm import (DeviceProgram, QueueOverflow,
-                                  queue_capacities, resident_unsupported)
+                                  queue_capacities, resident_unsupported,
+                                  shared_dram)
 from repro.core.vector_vm import VLEN, VectorVM
 from repro.kernels.device_loop import ring_peek, ring_push, window_compact
 
@@ -213,10 +214,11 @@ def test_loop_module_name_and_scopes():
     ``revet.ready`` around the ready snapshot, and one
     ``revet.ctx.<name>.<id>`` around each context's cond."""
     app, g = _dfg()
-    dp = DeviceProgram(g)
+    dp = DeviceProgram(g, shared=shared_dram(g, [app.dram_init]))
+    assert dp.shared        # murmur3's read-only input rides outside the carry
     dp._build()
-    st = dp._init_state(dict(app.dram_init), [dict(app.params)])
-    text = dp._jit_run.lower(st).compile().as_text()
+    st, shared, _ = dp._init_state(dict(app.dram_init), [dict(app.params)])
+    text = dp._jit_run.lower(st, shared).compile().as_text()
     assert text.startswith("HloModule jit_run")
     names = set(re.findall(r'op_name="([^"]*)"', text))
     assert any("/revet.loop/revet.ready/" in n for n in names)

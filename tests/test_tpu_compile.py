@@ -18,6 +18,7 @@ jax = pytest.importorskip("jax")
 import revet
 from benchmarks.common import BENCH_SIZES
 from repro.apps import ALL_APPS
+from repro.core.device_vm import shared_dram
 
 
 @pytest.fixture(scope="module")
@@ -61,19 +62,35 @@ def test_resident_program_compiles_for_v5e(name, n_requests, one_chip,
                                      execution="resident"))
     result = compiled.result
     # the launch shape the serving path builds (api.run_fused): pools scale
-    # with the batch, DRAM images are fused per request
+    # with the batch, arrays every request carries equal and the program
+    # only reads are laid out once, the rest are fused per request
+    inits = [app.dram_init] * n_requests
+    shared = shared_dram(result.dfg, inits)
     dp = compiled.backend.compile_resident(
         result, placement=compiled.placement, n_requests=n_requests,
         pool_override={p: pool.n_bufs * n_requests
-                       for p, pool in result.dfg.pools.items()})
+                       for p, pool in result.dfg.pools.items()},
+        shared=shared)
     dp._build()
-    fused = revet.fuse_dram_images(result.dfg, [app.dram_init] * n_requests)
-    state = dp._init_state(fused, [dict(app.params)] * n_requests)
-    specs = {k: jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=one_chip)
-             for k, v in state.items()}
-    exe = dp._jit_run.lower(specs).compile()
+    fused = revet.fuse_dram_images(result.dfg, inits, shared)
+    state, operands, _ = dp._init_state(fused, [dict(app.params)] * n_requests)
+
+    def specs(tree):
+        return {k: jax.ShapeDtypeStruct(np.shape(v), v.dtype,
+                                        sharding=one_chip)
+                for k, v in tree.items()}
+
+    exe = dp._jit_run.lower(specs(state), specs(operands)).compile()
     mem = exe.memory_analysis()
     assert mem.argument_size_in_bytes > 0
-    # every DRAM array rides in as an argument
-    dram_bytes = sum(4 * d.size * n_requests for d in result.dfg.dram.values())
+    # every DRAM array rides in as an argument: a shared one once, the
+    # others once per request
+    sizes = {n: d.size for n, d in result.dfg.dram.items()}
+    assert set(operands) == {f"d_{n}" for n in shared}
+    dram_bytes = sum(4 * sz * (1 if n in shared else n_requests)
+                     for n, sz in sizes.items())
     assert mem.argument_size_in_bytes >= dram_bytes
+    # and the loop hands back only the per-request ones
+    assert mem.output_size_in_bytes >= sum(
+        4 * sz * n_requests for n, sz in sizes.items() if n not in shared)
+    assert not any(f"d_{n}" in state for n in shared)
