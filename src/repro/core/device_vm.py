@@ -192,17 +192,12 @@ class _SharedArray:
         self.view = host.astype(np.int64)
         self.view.setflags(write=False)
 
-    def matches(self, value) -> bool:
-        if value is None:
-            return self.n is None
-        v = np.ravel(value)
-        return self.n == v.size and np.array_equal(v, self.host[: v.size])
-
 
 # One launch's host-to-device account: the shared arrays it read (name ->
-# _SharedArray), the bytes it copied from the host, and how many shared
-# arrays it had to make anew instead of reusing.
-_Upload = collections.namedtuple("_Upload", "arrays sent fresh")
+# _SharedArray), the bytes it copied from the host, how many shared arrays
+# it had to make anew instead of reusing, and the bytes of the launch's
+# values that the residency check compared.
+_Upload = collections.namedtuple("_Upload", "arrays sent fresh compared")
 
 
 class SharedDram:
@@ -216,17 +211,26 @@ class SharedDram:
     def __init__(self):
         self._arrays: dict[str, _SharedArray] = {}
 
-    def get(self, name: str, dtype: str, size: int, value
-            ) -> tuple[_SharedArray, bool]:
-        """The resident buffer for ``value`` (None: absent, all zeros),
-        made anew when it differs from the one held; returns the entry and
-        whether it was made anew.  An absent value is zeroed on the device
-        and copies nothing from the host."""
+    def held(self, name: str, value) -> tuple[Optional[_SharedArray], int]:
+        """The resident entry of ``name`` if it holds ``value`` (None:
+        absent, all zeros), else None; and the bytes of ``value`` that the
+        residency check compared to find out."""
+        e = self._arrays.get(name)
+        if e is None:
+            return None, 0
+        if value is None:
+            return (e if e.n is None else None), 0
+        v = np.ravel(value)
+        if e.n != v.size:
+            return None, 0
+        return (e if np.array_equal(v, e.host[: v.size]) else None), v.nbytes
+
+    def put(self, name: str, dtype: str, size: int, value) -> _SharedArray:
+        """A resident buffer made anew for ``value``, in place of the one
+        held.  An absent value is zeroed on the device and copies nothing
+        from the host."""
         import jax.numpy as jnp
         from .backend import wrap_dram_init
-        e = self._arrays.get(name)
-        if e is not None and e.matches(value):
-            return e, False
         self._arrays.pop(name, None)    # free the old buffer first
         if value is None:
             e = _SharedArray(np.zeros(size, np.int32), None,
@@ -237,7 +241,7 @@ class SharedDram:
             host[: w.size] = w
             e = _SharedArray(host, w.size, jnp.asarray(host))
         self._arrays[name] = e
-        return e, True
+        return e
 
 
 class DeviceProgram:
@@ -341,6 +345,7 @@ class DeviceProgram:
         ``dram_init`` holds the fused per-request images and, for each
         shared array, the one value every request carries."""
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
         from .backend import wrap_dram_init
         g = self.g
         if len(params_list) != self.n_requests:
@@ -382,14 +387,23 @@ class DeviceProgram:
         st["lt"] = jnp.zeros(len(self.lids), jnp.int32)
         shared: dict = {}
         arrays: dict = {}
+        compared = 0
+        if self.shared:
+            with TraceAnnotation("revet.launch.upload.check"):
+                for name in sorted(self.shared):
+                    arrays[name], n = self.shared_store.held(
+                        name, dram_init.get(name))
+                    compared += n
         for name, d in g.dram.items():
             if name in self.shared:
-                e, made = self.shared_store.get(name, d.dtype, d.size,
-                                                dram_init.get(name))
-                arrays[name], shared[f"d_{name}"] = e, e.dev
-                fresh += made
-                if made and e.n is not None:
-                    sent += e.host.nbytes
+                e = arrays[name]
+                if e is None:
+                    e = arrays[name] = self.shared_store.put(
+                        name, d.dtype, d.size, dram_init.get(name))
+                    fresh += 1
+                    if e.n is not None:
+                        sent += e.host.nbytes
+                shared[f"d_{name}"] = e.dev
                 continue
             a = np.zeros(d.size * self.n_requests, np.int32)
             if name in dram_init:
@@ -433,7 +447,7 @@ class DeviceProgram:
         st["prog"] = jnp.asarray(True)
         st["err"] = jnp.zeros((), jnp.int32)
         st["tick"] = jnp.zeros((), jnp.int32)
-        return st, shared, _Upload(arrays, sent, fresh)
+        return st, shared, _Upload(arrays, sent, fresh, compared)
 
     # ------------------------------------------------------------- jit build
     def _build(self) -> None:
@@ -1190,7 +1204,8 @@ class DeviceProgram:
             st, shared, up = self._init_state(dram_init, params_list)
             span.set_metadata(shared=len(self.shared),
                               reused=int(bool(self.shared) and not up.fresh),
-                              upload_mib=up.sent / 2 ** 20)
+                              upload_mib=up.sent / 2 ** 20,
+                              compared_mib=up.compared / 2 ** 20)
         with TraceAnnotation("revet.launch.loop"):
             out = jax.block_until_ready(self._jit_run(st, shared))
         with TraceAnnotation("revet.launch.readback"):

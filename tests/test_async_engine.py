@@ -485,8 +485,9 @@ LAUNCH_SPANS = ("revet.batch.check", "revet.batch.fuse",
 
 def test_resident_pump_spans_in_profile(ip2int_jax, tmp_path):
     """A profiler capture of one resident pump holds the launch path's
-    eight spans: ``revet.pump`` with its arguments, and the seven stages
-    inside it, once each and in order."""
+    nine spans: ``revet.pump`` with its arguments, the seven stages inside
+    it, once each and in order, and the shared arrays' residency check
+    inside the upload."""
     import jax
     from jax.profiler import ProfileData
     app, compiled = ip2int_jax
@@ -511,7 +512,8 @@ def test_resident_pump_spans_in_profile(ip2int_jax, tmp_path):
                 if e.name.startswith("revet."):
                     assert e.name not in spans, e.name
                     spans[e.name] = (e.start_ns, e.end_ns, dict(e.stats))
-    assert set(spans) == {"revet.pump", *LAUNCH_SPANS}
+    assert set(spans) == {"revet.pump", "revet.launch.upload.check",
+                          *LAUNCH_SPANS}
     p0, p1, args = spans["revet.pump"]
     assert args == {"launch": 1, "size": 2, "served": 1}
     assert spans["revet.batch.check"][2] == {"requests": 1}
@@ -519,9 +521,13 @@ def test_resident_pump_spans_in_profile(ip2int_jax, tmp_path):
     # the upload counts the shared read-only arrays; launch 0 left them
     # resident, so this launch copies only the per-request words
     up = spans["revet.launch.upload"][2]
-    assert set(up) == {"shared", "reused", "upload_mib"}
+    assert set(up) == {"shared", "reused", "upload_mib", "compared_mib"}
     assert up["shared"] >= 1 and up["reused"] == 1
     assert 0 < up["upload_mib"] < 1
+    assert up["compared_mib"] > 0
+    c0, c1, _ = spans["revet.launch.upload.check"]
+    assert spans["revet.launch.upload"][0] <= c0 <= c1 \
+        <= spans["revet.launch.upload"][1]
     starts = [spans[n][0] for n in LAUNCH_SPANS]
     assert starts == sorted(starts)
     for n in LAUNCH_SPANS:

@@ -207,3 +207,67 @@ def test_engine_counts_shared_uploads_and_reuses(table):
     st = eng.stats()
     assert st["launches"] == 3
     assert (st["shared_uploads"], st["shared_reuses"]) == (1, 2)
+
+
+def _upload_spans(tmp_path, launch):
+    """The ``revet.launch.upload*`` spans of one profiled ``launch()``:
+    name -> (start, end, arguments)."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        launch()
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("revet.launch.upload"):
+                    assert e.name not in spans, e.name
+                    spans[e.name] = (e.start_ns, e.end_ns, dict(e.stats))
+    return spans
+
+
+def test_residency_check_is_a_span_with_the_bytes_compared(table, tmp_path):
+    """A launch that reuses the resident table compares the launch's value
+    with the host image inside ``revet.launch.upload.check``, and
+    ``revet.launch.upload`` counts the bytes compared."""
+    app, compiled = table
+    tk, tv = _fresh_table(app, 9)
+    reqs = _requests(app, tk, tv, 9)
+    first = compiled.execute_batch(reqs, execution="resident")
+    assert first.report.stats["shared_uploads"] == 1
+    out = []
+    spans = _upload_spans(tmp_path, lambda: out.append(
+        compiled.execute_batch(reqs, execution="resident")))
+    assert out[0].report.stats["shared_reuses"] == 1
+    _assert_matches_sequential(out[0], compiled, reqs)
+    assert set(spans) == {"revet.launch.upload",
+                          "revet.launch.upload.check"}
+    u0, u1, args = spans["revet.launch.upload"]
+    c0, c1, _ = spans["revet.launch.upload.check"]
+    assert u0 <= c0 <= c1 <= u1
+    assert args["reused"] == 1 and args["shared"] == 2
+    assert args["compared_mib"] == pytest.approx(
+        (tk.nbytes + tv.nbytes) / 2 ** 20)
+
+
+def test_residency_check_counts_the_bytes_it_compares():
+    """Nothing is resident under a name the store has not seen, and a value
+    of another size is not compared: both read as not held, 0 bytes.  A
+    value of the held size is compared whole, equal or not."""
+    from repro.core.device_vm import SharedDram
+    store = SharedDram()
+    value = np.arange(10, dtype=np.int32)
+    assert store.held("a", value) == (None, 0)
+    e = store.put("a", "i32", 16, value)
+    assert store.held("a", value) == (e, value.nbytes)
+    assert store.held("a", value[:5]) == (None, 0)
+    assert store.held("a", value + 1) == (None, value.nbytes)
+    assert store.held("a", None) == (None, 0)
+    zeros = store.put("a", "i32", 16, None)
+    assert store.held("a", None) == (zeros, 0)
