@@ -6,9 +6,12 @@ pays ~``ticks`` host round-trips (92–6700 on the Table III apps).  This
 module compiles a placed program's *entire* superstep schedule into a
 single ``jax.jit``-ed ``lax.while_loop`` over ticks:
 
-* every inter-context queue is a fixed-capacity device ring (kinds column,
-  payload block whose last column is the hidden request id, and a row in
-  the shared head/tail vectors — see ``kernels/device_loop.py``);
+* every inter-context queue is a fixed-capacity device ring (a kinds
+  vector, one vector per payload column with the hidden request id last,
+  and a row in the shared head/tail vectors — see
+  ``kernels/device_loop.py``);
+* each context fires windows of up to ``W`` lanes a tick, ``W`` following
+  the launch's request count (:func:`launch_width`);
 * each context's fire/stall decision is a masked tensor computation inside
   the loop body (readiness is evaluated against the tick-start head/tail
   snapshot, exactly like the host scheduler's ready-set snapshot);
@@ -126,21 +129,43 @@ def resident_unsupported(g: DFG) -> list[str]:
     return reasons
 
 
+# Widest window a resident launch fires.  On a TPU v5e, a launch of 8
+# requests ran its loop in 1,069 ms at 1,024 lanes and 1,386 ms at 512 (the
+# q9 scan; DESIGN.md §9), but its rings, 4W slots or more a link, then
+# raised the process's peak device memory above the 128-lane loop's, where
+# 512 lanes keep it under.
+MAX_WIDTH = 512
+
+
+def launch_width(n_requests: int) -> int:
+    """Lanes a resident launch of ``n_requests`` fires per context per
+    tick: each request brings one ``VLEN``-lane slice of issue width, as
+    the replicated VM's replicas do, up to :data:`MAX_WIDTH`."""
+    return min(max(int(n_requests), 1) * VLEN, MAX_WIDTH)
+
+
+def ring_floor(vlen: int) -> int:
+    """Fewest slots a ring takes for windows of ``vlen`` lanes, and the
+    :class:`DeviceProgram` pre-check's floor: a peek needs ``2*vlen``, and
+    a ``2*vlen`` reduce push must not meet the front window it wraps
+    onto.  Every push is gated by its ring's room, so nothing more is
+    needed for the loop not to overflow."""
+    return 4 * vlen
+
+
 def queue_capacities(g: DFG, placement=None, vlen: int = VLEN
                      ) -> dict[int, int]:
-    """Ring capacity per link for the resident executor.
-
-    The floor is ``8*vlen`` (full windows plus protocol-emission headroom;
-    the :class:`DeviceProgram` pre-check requires ``>= 4*vlen``).  When a
-    placement is given, its per-context deadlock/retiming buffer
-    attribution (``machine.map_graph``) scales the floor — delegated to
+    """Ring capacity per link for the resident executor, for windows of
+    ``vlen`` lanes: :func:`ring_floor`, scaled, when a placement is given,
+    by its per-context deadlock/retiming buffer attribution
+    (``machine.map_graph``) — delegated to
     :meth:`~repro.core.place.Placement.queue_capacities`, so the budgets
     that size the physical FIFOs size the device rings.
     """
+    floor = ring_floor(vlen)
     if placement is not None:
-        return placement.queue_capacities(g, vlen=vlen)
-    base = 8 * vlen
-    return {lid: min(1 << 16, _next_pow2(base)) for lid in g.links}
+        return placement.queue_capacities(g, floor=floor)
+    return {lid: min(1 << 16, _next_pow2(floor)) for lid in g.links}
 
 
 _DTYPE_MASK = {"i8": 0xFF, "i16": 0xFFFF, "i32": None}
@@ -248,7 +273,9 @@ class DeviceProgram:
     """One DFG compiled to a single resident device launch.
 
     Specialized per ``(n_requests, vlen, queue capacities, pool sizes,
-    shared arrays)`` — the front-end caches instances per shape
+    shared arrays)``; ``vlen``, the lanes a context fires per tick, follows
+    from ``n_requests`` (:func:`launch_width`) unless given.  The front-end
+    caches instances per shape
     (``CompiledProgram``), so a serving deployment jit-compiles once per
     launch shape, exactly like the windowed jax path's per-window kernel
     cache but with *one* cache entry for the whole program.
@@ -258,7 +285,8 @@ class DeviceProgram:
     the programs of every launch shape share.
     """
 
-    def __init__(self, g: DFG, *, n_requests: int = 1, vlen: int = VLEN,
+    def __init__(self, g: DFG, *, n_requests: int = 1,
+                 vlen: Optional[int] = None,
                  queue_caps: dict[int, int] | None = None, placement=None,
                  pool_override: dict[str, int] | None = None,
                  max_ticks: int = 1_000_000, shared=frozenset(),
@@ -275,18 +303,18 @@ class DeviceProgram:
         self.shared_store = shared_store if shared_store is not None \
             else SharedDram()
         self.g = g
-        self.vlen = int(vlen)
         self.n_requests = int(n_requests)
+        self.vlen = launch_width(self.n_requests) if vlen is None \
+            else int(vlen)
         self.max_ticks = int(max_ticks)
         self.launches = 1
         self.backend = None      # ExecutorBackend, set by compile_resident
-        caps = dict(queue_capacities(g, placement, vlen))
+        caps = dict(queue_capacities(g, placement, self.vlen))
         caps.update(queue_caps or {})
         # host-side capacity pre-check: a ready context can push up to two
-        # tokens per input lane (reduce emissions) plus protocol barriers,
-        # and back-pressure only gates at window granularity — 4*vlen is
-        # the proven-safe floor (DESIGN.md §9)
-        floor = 4 * self.vlen
+        # tokens per input lane (reduce emissions), within its ring's room
+        # (DESIGN.md §9)
+        floor = ring_floor(self.vlen)
         for lid, cap in caps.items():
             if cap < floor or cap & (cap - 1):
                 l = g.links[lid]
@@ -300,7 +328,14 @@ class DeviceProgram:
         self.lids = sorted(g.links)
         self.row_of = {lid: i for i, lid in enumerate(self.lids)}
         self.src_row = len(self.lids)
-        self.src_cap = _next_pow2(max(64, self.n_requests + 1, 2 * vlen))
+        self.src_cap = _next_pow2(max(64, self.n_requests + 1,
+                                      2 * self.vlen))
+        # scratch pad past each ring's capacity: its widest push, 2W where
+        # a reduce output feeds it, else W (a window or a peek)
+        reduce_fed = {o.link for c in g.contexts.values() for o in c.outs
+                      if o.kind == "reduce"}
+        self.pad_of = {lid: (2 if lid in reduce_fed else 1) * self.vlen
+                       for lid in self.lids}
         self.source_vars = tuple(getattr(g, "source_vars", ()))
         self._dram_lim = {name: d.size for name, d in g.dram.items()}
         self._dram_mask = {name: _DTYPE_MASK[d.dtype]
@@ -362,27 +397,28 @@ class DeviceProgram:
 
         st: dict = {}
         n_rings = len(self.lids) + 1
-        pad = 2 * self.vlen           # scratch pad: widest push is 2W (reduce)
         qh = np.zeros(n_rings, np.int32)
         qt = np.zeros(n_rings, np.int32)
+        # a ring is a kinds vector and one vector per payload column, the
+        # request id last (kernels/device_loop.py)
         for lid in self.lids:
             l = g.links[lid]
-            cap = self.caps[lid]
-            st[f"qk{lid}"] = jnp.zeros(cap + pad, jnp.int32)
-            st[f"qv{lid}"] = jnp.zeros((cap + pad, len(l.vars) + 1),
-                                       jnp.int32)
-        # source ring: one parameter row per request, then the closing Ω1
-        sk = np.zeros(self.src_cap + pad, np.int32)
-        sv = np.zeros((self.src_cap + pad, len(self.source_vars) + 1),
-                      np.int32)
+            size = self.caps[lid] + self.pad_of[lid]
+            st[f"qk{lid}"] = jnp.zeros(size, jnp.int32)
+            st[f"qv{lid}"] = tuple(jnp.zeros(size, jnp.int32)
+                                   for _ in range(len(l.vars) + 1))
+        # source ring: one parameter slot per request, then the closing Ω1
+        size = self.src_cap + self.vlen
+        sk = np.zeros(size, np.int32)
+        sv = np.zeros((len(self.source_vars) + 1, size), np.int32)
         for r, params in enumerate(params_list):
-            sv[r, : len(self.source_vars)] = [
+            sv[: len(self.source_vars), r] = [
                 ir.wrap32(int(params[p])) for p in self.source_vars]
-            sv[r, -1] = r
+            sv[-1, r] = r
         sk[self.n_requests] = 1
         qt[self.src_row] = self.n_requests + 1
         st["qkS"] = put(sk)
-        st["qvS"] = put(sv)
+        st["qvS"] = tuple(put(c) for c in sv)
         st["qh"], st["qt"] = put(qh), put(qt)
         st["lt"] = jnp.zeros(len(self.lids), jnp.int32)
         shared: dict = {}
@@ -444,6 +480,7 @@ class DeviceProgram:
         st["red_acc"] = put(racc)
         st["red_open"] = jnp.zeros(n_red, bool)
         st["stats"] = jnp.zeros(len(self._stat_keys), jnp.int32)
+        st["lanes"] = jnp.zeros((), jnp.int32)
         st["prog"] = jnp.asarray(True)
         st["err"] = jnp.zeros((), jnp.int32)
         st["tick"] = jnp.zeros((), jnp.int32)
@@ -659,6 +696,9 @@ class DeviceProgram:
             return r if r is not None else jnp.zeros(P, I32)
 
         def route_window(st, ctx, kinds, regs, n):
+            # every window a context fires passes here once: count its
+            # data and barrier lanes (occupancy = lanes / (ticks * W))
+            st["lanes"] = st["lanes"] + n
             P = kinds.shape[0]
             lanes = jnp.arange(P, dtype=I32)
             valid = lanes < n
@@ -676,7 +716,7 @@ class DeviceProgram:
                     st["red_acc"] = st["red_acc"].at[ri].set(nacc)
                     st["red_open"] = st["red_open"].at[ri].set(nopen)
                     cols = ([ov] if nv > 1 else []) + [orid]
-                    push(st, o.link, ok_, jnp.stack(cols, axis=1), cnt)
+                    push(st, o.link, ok_, cols, cnt)
                     continue
                 cols = [rget(regs, v, P) for v in o.values] + [rid]
                 while len(cols) < nv:       # valueless outs: zero payload
@@ -684,7 +724,7 @@ class DeviceProgram:
                 if o.kind == "pass" and not o.lower_barrier:
                     # pass-through: lanes [0, n) are already contiguous, so
                     # the compaction scatter is a no-op — push directly
-                    push(st, o.link, kinds, jnp.stack(cols, axis=1), n)
+                    push(st, o.link, kinds, cols, n)
                     continue
                 if o.kind == "discard":
                     keep = valid & ~data
@@ -696,8 +736,7 @@ class DeviceProgram:
                 if o.lower_barrier:
                     keep = keep & (kinds != 1)
                     out_kinds = jnp.where(kinds > 1, kinds - 1, kinds)
-                kb, vb, cnt = dl.window_compact(
-                    keep, out_kinds, jnp.stack(cols, axis=1))
+                kb, vb, cnt = dl.window_compact(keep, out_kinds, cols)
                 push(st, o.link, kb, vb, cnt)
 
         def empty_regs1(vars_, rid):
@@ -719,8 +758,8 @@ class DeviceProgram:
             n = jnp.minimum(budget, qlen(st, ridx))
             kinds, vals = dl.ring_peek(st[kk], st[vk], st["qh"][ridx], cap, W)
             n = alloc_limit(st, ctx, kinds, n)
-            regs = {v: vals[:, i] for i, v in enumerate(vars_)}
-            regs[RID] = vals[:, -1]
+            regs = {v: vals[i] for i, v in enumerate(vars_)}
+            regs[RID] = vals[-1]
             regs = exec_body(st, ctx, kinds, regs, n)
             route_window(st, ctx, kinds, regs, n)
             st["qh"] = st["qh"].at[ridx].add(n)
@@ -747,8 +786,8 @@ class DeviceProgram:
             regs = {}
             for (ko, vo, _), l in zip(peeks, h.links):
                 for i, v in enumerate(g.links[l].vars):
-                    regs[v] = vo[:, i]
-            regs[RID] = peeks[0][1][:, -1]
+                    regs[v] = vo[i]
+            regs[RID] = peeks[0][1][-1]
             regs = exec_body(st, ctx, ref, regs, L)
             route_window(st, ctx, ref, regs, L)
             for l in h.links:
@@ -759,7 +798,13 @@ class DeviceProgram:
             nv = len(g.links[h.a].vars) + 1
             r = room(st, ctx)
             gate = rdy & (r > 0)
-            budget = jnp.where(gate, jnp.clip(r // room_div[ctx.id], 0, W), 0)
+            # an alloc stall in a merge fails the launch, so an allocating
+            # merge keeps VLEN-lane windows, as the replicated VM's does:
+            # wider ones would raise the pool size a program that completes
+            # on one request needs
+            mw = min(VLEN, W) if self._ctx_alloc_pools[ctx.id] else W
+            budget = jnp.where(gate, jnp.clip(r // room_div[ctx.id], 0, mw),
+                               0)
             fired = jnp.asarray(False)
             # two greedy sub-steps per tick: a-run, else b-run, else the
             # leading equal-barrier-pair run (host assembles these into one
@@ -784,21 +829,19 @@ class DeviceProgram:
                               jnp.where(take_b, rb,
                                         jnp.where(take_p, npair, 0)))
                 kinds = jnp.where(take_b, kb, ka)
-                vsel = jnp.where(take_b, vb, va)
-                if nv > 1:     # pair barriers keep only their request id
-                    prow = jnp.concatenate(
-                        [jnp.zeros((W, nv - 1), I32), va[:, -1:]], axis=1)
-                else:
-                    prow = va
-                vsel = jnp.where(take_p, prow, vsel)
+                # pair barriers keep only their request id
+                zero = jnp.zeros(W, I32)
+                vsel = [jnp.where(take_p, zero if i < nv - 1 else a_,
+                                  jnp.where(take_b, b_, a_))
+                        for i, (a_, b_) in enumerate(zip(va, vb))]
                 nl = alloc_limit(st, ctx, kinds, n)
                 astall = nl < n
                 st["err"] = jnp.where(astall & (st["err"] == 0),
                                       _ERR_MERGE_ALLOC + ctx.id, st["err"])
                 n = jnp.where(astall, 0, n)
-                regs = {v: vsel[:, i]
+                regs = {v: vsel[i]
                         for i, v in enumerate(g.links[h.a].vars)}
-                regs[RID] = vsel[:, -1]
+                regs[RID] = vsel[-1]
                 regs = exec_body(st, ctx, kinds, regs, n)
                 route_window(st, ctx, kinds, regs, n)
                 pop(st, h.a, jnp.where(take_a | take_p, n, 0))
@@ -843,9 +886,9 @@ class DeviceProgram:
             kin, vin, lin = peek(st, h.link, W)
             in_valid = LANES < jnp.minimum(lin, W)
             is_d = in_valid & (kin == 0)
-            lo_v = vin[:, lo_i]
-            hi_v = vin[:, hi_i]
-            sp_v = jnp.where(vin[:, st_i] == 0, 1, vin[:, st_i])
+            lo_v = vin[lo_i]
+            hi_v = vin[hi_i]
+            sp_v = jnp.where(vin[st_i] == 0, 1, vin[st_i])
             e_i = jnp.where(is_d & (sp_v > 0),
                             jnp.maximum(-((lo_v - hi_v) // sp_v), 0), 0)
             sz = jnp.where(is_d, e_i + add_i, jnp.where(in_valid, 1, 0))
@@ -864,7 +907,8 @@ class DeviceProgram:
             new_cur = jnp.where(load_big, lo_v[0], cur + step * c_emit)
             new_hi = jnp.where(load_big, hi_v[0], hi)
             new_step = jnp.where(load_big, sp_v[0], step)
-            new_base = jnp.where(load_big, vin[0], base)
+            new_base = jnp.where(load_big, jnp.stack([c[0] for c in vin]),
+                                 base)
             pop_n = jnp.where(load_big, 1, rows_taken)
             # assemble the output window: carried prefix, then intake rows
             n_win = prefix + total_in
@@ -882,10 +926,11 @@ class DeviceProgram:
             use_car = LANES < prefix
             kinds = jnp.where(use_car, k_car, k_int)
             ivar = jnp.where(use_car, iv_car, iv_int)
-            pl = jnp.where(use_car[:, None], base[None, :], vin[rowi])
-            regs = {v: pl[:, i] for i, v in enumerate(vars_in)}
+            pl = [jnp.where(use_car, base[i], c[rowi])
+                  for i, c in enumerate(vin)]
+            regs = {v: pl[i] for i, v in enumerate(vars_in)}
             regs[h.ivar] = ivar
-            regs[RID] = pl[:, -1]
+            regs[RID] = pl[-1]
             regs = exec_body(st, ctx, kinds, regs, n_win)
             route_window(st, ctx, kinds, regs, n_win)
             pop(st, h.link, pop_n)
@@ -920,14 +965,14 @@ class DeviceProgram:
             tok_bar = have & (kin[0] > 0)
             # pass-through barrier: 1-lane route, no body
             route_window(st, ctx, jnp.reshape(kin[0] + add_i, (1,)),
-                         empty_regs1(list(vars_in) + [h.ivar], vin[0, -1]),
+                         empty_regs1(list(vars_in) + [h.ivar], vin[-1][0]),
                          jnp.where(tok_bar, 1, 0))
             act2 = act | tok_data
-            cur2 = jnp.where(tok_data, vin[0, lo_i], cur)
-            hi2 = jnp.where(tok_data, vin[0, hi_i], hi)
-            sraw = vin[0, st_i]
+            cur2 = jnp.where(tok_data, vin[lo_i][0], cur)
+            hi2 = jnp.where(tok_data, vin[hi_i][0], hi)
+            sraw = vin[st_i][0]
             step2 = jnp.where(tok_data, jnp.where(sraw == 0, 1, sraw), step)
-            base2 = jnp.where(tok_data, vin[0], base)
+            base2 = jnp.where(tok_data, jnp.stack([c[0] for c in vin]), base)
             pop(st, h.link, jnp.where(tok_data | tok_bar, 1, 0))
             rem = jnp.where(act2 & (step2 > 0) & gate,
                             jnp.maximum(-((cur2 - hi2) // jnp.where(
@@ -944,10 +989,10 @@ class DeviceProgram:
             n_win = emit + close.astype(I32)
             kinds = jnp.where(LANES < emit, 0,
                               jnp.where((LANES == emit) & close, 1, 0))
-            pl = jnp.broadcast_to(base2[None, :], (W, base2.shape[0]))
-            regs = {v: pl[:, i] for i, v in enumerate(vars_in)}
+            regs = {v: jnp.broadcast_to(base2[i], (W,))
+                    for i, v in enumerate(vars_in)}
             regs[h.ivar] = cur2 + step2 * LANES
-            regs[RID] = pl[:, -1]
+            regs[RID] = jnp.broadcast_to(base2[-1], (W,))
             regs = exec_body(st, ctx, kinds, regs, n_win)
             route_window(st, ctx, kinds, regs, n_win)
             st["cnt_act"] = st["cnt_act"].at[ci].set(act2 & ~complete)
@@ -963,7 +1008,7 @@ class DeviceProgram:
             vars_f = g.links[h.fwd].vars
             r = room(st, ctx)
             gate = rdy & (r > 0)
-            budget = jnp.where(gate, jnp.clip(r // room_div[cid], 0, W), 0)
+            budget0 = jnp.where(gate, jnp.clip(r // room_div[cid], 0, W), 0)
             mode = st[f"fb_mode_{cid}"]
             pend = st[f"fb_pend_{cid}"]
             got = st[f"fb_got_{cid}"]
@@ -972,25 +1017,29 @@ class DeviceProgram:
             # -- ordered release: oldest non-echo session, if it is waiting
             sess = (mode == 1) | (mode == 2)
             rid_old = jnp.argmin(jnp.where(sess, seq, BIG)).astype(I32)
-            can_rel = gate & sess.any() & (mode[rid_old] == 2)
+            can_rel = gate & (budget0 > 0) & sess.any() & \
+                (mode[rid_old] == 2)
             route_window(st, ctx, jnp.reshape(pend[rid_old] + 1, (1,)),
                          empty_regs1(vars_f, rid_old),
                          jnp.where(can_rel, 1, 0))
             mode = mode.at[rid_old].set(jnp.where(can_rel, 3, mode[rid_old]))
+            # every lane routed below comes out of the budget, as the host's
+            # running ``budget -= ...`` does: the release's lane first
+            budget = budget0 - jnp.where(can_rel, 1, 0)
             # -- backedge: leading data run, then one head barrier
             kb, vb, lb = peek(st, h.back, W)
             brun = dl.leading_run(kb == 0, jnp.minimum(lb, budget))
             bn = alloc_limit(st, ctx, kb, brun)
-            regsb = {v: vb[:, i] for i, v in enumerate(vars_f)}
-            regsb[RID] = vb[:, -1]
+            regsb = {v: vb[i] for i, v in enumerate(vars_f)}
+            regsb[RID] = vb[-1]
             regsb = exec_body(st, ctx, kb, regsb, bn)
             route_window(st, ctx, kb, regsb, bn)
-            wrids = jnp.clip(vb[:, -1], 0, nreq - 1)
+            wrids = jnp.clip(vb[-1], 0, nreq - 1)
             wmask = (LANES < bn) & (mode[wrids] > 0)
             got = got.at[jnp.where(wmask, wrids, nreq)].set(True, mode="drop")
-            hb = gate & (brun == 0) & (lb > 0) & (kb[0] > 0)
+            hb = gate & (budget > 0) & (brun == 0) & (lb > 0) & (kb[0] > 0)
             lvl = kb[0]
-            brid = jnp.clip(vb[0, -1], 0, nreq - 1)
+            brid = jnp.clip(vb[-1][0], 0, nreq - 1)
             m_r = mode[brid]
             bad = hb & ((m_r == 0) | (m_r == 2) |
                         ((m_r == 1) & (lvl != 1)) |
@@ -1008,28 +1057,30 @@ class DeviceProgram:
                 jnp.where(d_case & ~emit_wave, 2,
                           jnp.where(e_case, 0, mode[brid])))
             pop_b = bn + jnp.where(d_case | e_case, 1, 0)
+            budget = budget - bn - jnp.where(emit_wave, 1, 0)
             pop(st, h.back, pop_b)
             # -- forward intake only once the backedge is drained (or its
             # run is alloc-stalled) — host drains qb before touching qf
             back_stalled = (brun > 0) & (bn == 0)
             allow_fwd = gate & (((lb - pop_b) == 0) | back_stalled)
-            fbudget = jnp.clip(budget - bn - 3, 0, W)
+            fbudget = jnp.clip(budget0 - bn - 3, 0, W)
             kf, vf, lf = peek(st, h.fwd, W)
             frun = dl.leading_run(kf == 0, jnp.minimum(lf, fbudget))
             frun = jnp.where(allow_fwd, frun, 0)
-            frids = jnp.clip(vf[:, -1], 0, nreq - 1)
+            frids = jnp.clip(vf[-1], 0, nreq - 1)
             if self.parallel_loops:
                 fblocked = (mode[frids] > 0) & (LANES < frun)
                 admit = dl.first_index(fblocked, frun)
             else:
                 admit = jnp.where((mode > 0).any(), 0, frun)
             fn = alloc_limit(st, ctx, kf, admit)
-            regsf = {v: vf[:, i] for i, v in enumerate(vars_f)}
-            regsf[RID] = vf[:, -1]
+            regsf = {v: vf[i] for i, v in enumerate(vars_f)}
+            regsf[RID] = vf[-1]
             regsf = exec_body(st, ctx, kf, regsf, fn)
             route_window(st, ctx, kf, regsf, fn)
             # -- group barrier: open a session (serial: only when idle)
-            ob = allow_fwd & (frun == 0) & (fn == 0) & (lf > 0) & (kf[0] > 0)
+            ob = allow_fwd & (budget > 0) & (frun == 0) & (fn == 0) & \
+                (lf > 0) & (kf[0] > 0)
             frid0 = frids[0]
             if self.parallel_loops:
                 can_open = ob & (mode[frid0] == 0)
@@ -1120,8 +1171,8 @@ class DeviceProgram:
         def write_set(ctx, st):
             """Abstract probe run of ``fire_ctx`` (no equations added to the
             enclosing jaxpr) to learn the context's written state keys."""
-            shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
-                      for k, v in st.items()}
+            shapes = jax.tree_util.tree_map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), st)
             wrote: set = set()
 
             def probe(s):
@@ -1183,8 +1234,10 @@ class DeviceProgram:
             return jax.lax.while_loop(cond, body, st)
 
         # the function's name fixes the XLA module's, ``jit_run``: the
-        # benchmark's loop_ms reads that module from the device trace
-        self._jit_run = jax.jit(run)
+        # benchmark's loop_ms reads that module from the device trace.
+        # The carry is donated: _init_state builds it afresh for every
+        # launch, so the loop's outputs reuse its buffers on the device.
+        self._jit_run = jax.jit(run, donate_argnums=0)
         self._tick = tick           # uncompiled tick body, for diagnostics
 
     # ----------------------------------------------------------- host driver
@@ -1206,7 +1259,7 @@ class DeviceProgram:
                               reused=int(bool(self.shared) and not up.fresh),
                               upload_mib=up.sent / 2 ** 20,
                               compared_mib=up.compared / 2 ** 20)
-        with TraceAnnotation("revet.launch.loop"):
+        with TraceAnnotation("revet.launch.loop", width=self.vlen):
             out = jax.block_until_ready(self._jit_run(st, shared))
         with TraceAnnotation("revet.launch.readback"):
             return self._finish(out, up)
@@ -1232,6 +1285,8 @@ class DeviceProgram:
         for k, i in self._stat_row.items():
             if sv[i]:
                 stats[k] = int(sv[i])
+        stats["width"] = self.vlen
+        stats["lanes_fired"] = int(out["lanes"])
         if self.shared:
             stats["shared_uploads" if up.fresh else "shared_reuses"] = 1
         lt = np.asarray(out["lt"])

@@ -96,27 +96,26 @@ class Placement:
         """Machine lanes the placed program owns (Fig. 12 x-axis)."""
         return self.replicas * self.params.lanes
 
-    def queue_capacities(self, g: DFG, vlen: int = 128,
-                         floor_windows: int = 8,
+    def queue_capacities(self, g: DFG, floor: int,
                          cap_max: int = 1 << 16) -> dict[int, int]:
         """Device ring capacity per link for the resident executor
         (DESIGN.md §9), sized from this placement's link-buffer budgets.
 
-        The floor is ``floor_windows * vlen`` words (full windows plus
-        protocol-emission headroom); each link then scales by its
-        destination context's buffer attribution from ``machine.map_graph``
-        — links into a loop header carry the §V-D(b) deadlock-avoidance
-        margin ``mu_deadlock``, links into a retimed merge/zip carry the
-        path-imbalance margin ``mu_retime``.  The same budgets that size
-        the physical FIFOs size the device rings.  Capacities round up to
-        powers of two (ring indexing masks) and clamp at ``cap_max``."""
+        ``floor`` is the fewest slots a ring takes
+        (:func:`~repro.core.device_vm.ring_floor` of the launch's window
+        width); each link then scales it by its destination context's
+        buffer attribution from ``machine.map_graph`` — links into a loop
+        header carry the §V-D(b) deadlock-avoidance margin ``mu_deadlock``,
+        links into a retimed merge/zip carry the path-imbalance margin
+        ``mu_retime``.  The same budgets that size the physical FIFOs size
+        the device rings.  Capacities round up to powers of two (ring
+        indexing masks) and clamp at ``cap_max``."""
         margin = {cid: 1 for cid in g.contexts}
         for cm in self.report.per_context:
             margin[cm.ctx_id] = 1 + cm.mu_deadlock + cm.mu_retime
-        base = floor_windows * vlen
         caps: dict[int, int] = {}
         for lid, l in g.links.items():
-            n = base * margin.get(l.dst, 1)
+            n = floor * margin.get(l.dst, 1)
             caps[lid] = min(cap_max, 1 << max(1, (int(n) - 1).bit_length()))
         return caps
 

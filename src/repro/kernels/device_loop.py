@@ -9,7 +9,8 @@ body**, so the whole superstep schedule compiles into one device program
 
 Every function here obeys the two rules that make that possible:
 
-* **fixed shapes** — windows are always ``W`` lanes (invalid lanes masked),
+* **fixed shapes** — windows are always ``W`` lanes (invalid lanes masked;
+  a payload is a tuple of ``(W,)`` registers, one per column),
   queues are fixed-capacity rings indexed modulo a power-of-two, and
   variable-length results come back as ``(buffer, count)`` pairs;
 * **no control flow** — fire/stall decisions are masked tensor ops
@@ -22,8 +23,8 @@ path disappear (XLA's int32 add/sub/mul/shl wrap exactly like ``ir.wrap32``).
 
 The SLTF token encoding matches ``core/sltf.py``: kind 0 = data, k>0 = Ω_k.
 Ring slots beyond ``tail-head`` hold garbage; every consumer masks by the
-valid count.  The hidden request-id column rides as the last payload column
-of every ring, exactly as in the windowed VM (DESIGN.md §7/§9).
+valid count.  The hidden request id rides as the last payload column of
+every ring, exactly as in the windowed VM (DESIGN.md §7/§9).
 """
 from __future__ import annotations
 
@@ -120,46 +121,68 @@ def _scatter_red(op: str, target, idx, vals):
 # ---------------------------------------------------------------------------
 # fixed-capacity ring queues
 # ---------------------------------------------------------------------------
-# A ring is (kinds:(cap+pad,), vals:(cap+pad,nv)) plus absolute head/tail
-# counters kept in a shared (n_links,) vector; cap is a power of two so
-# position = counter & (cap-1).  head==tail means empty; tail-head is the
-# live length.  The trailing ``pad`` slots are *scratch*: pushes write one
-# contiguous window at the tail (spilling past ``cap`` into the pad) and
-# re-issue the wrapped lanes at the front, which stays authoritative;
-# peeks re-read wrapped lanes from the front.  Contiguous
-# dynamic-slice/dynamic-update-slice windows lower to memcpys on XLA CPU,
-# while the modular gather/scatter form costs a bounds-checked loop per
-# lane — the dominant per-tick cost of the fused loop.  Crucially, every
-# read of the pre-push ring is scheduled before the first update, so XLA
-# updates the ring buffer in place instead of copying it per push.
+# A ring is kinds:(cap+pad,) and one (cap+pad,) vector per payload column,
+# plus absolute head/tail counters kept in a shared (n_links,) vector; cap
+# is a power of two so position = counter & (cap-1).  head==tail means
+# empty; tail-head is the live length.  Every array of a ring is 1-D, so
+# its slots always run along the lanes: a TPU tiles it in 4 B a slot, and
+# XLA has no second layout to move it into (a 2-D payload gets relaid out
+# with its few columns padded to 128 lanes, 512 B a slot, around the
+# dynamic slices).  A window is likewise a (W,) kinds vector and a tuple
+# of (W,) registers, one per column.  The trailing ``pad`` slots are
+# *scratch*: pushes write one contiguous window at the tail (spilling past
+# ``cap`` into the pad) and re-issue the wrapped lanes at the front, which
+# stays authoritative; peeks re-read wrapped lanes from the front.
+# Contiguous dynamic-slice / dynamic-update-slice windows lower to memcpys
+# on XLA CPU, while the modular gather/scatter form costs a bounds-checked
+# loop per lane — the dominant per-tick cost of the fused loop.  Crucially,
+# every read of the pre-push ring is scheduled before the first update, so
+# XLA updates the ring buffers in place instead of copying them per push.
 
-def ring_peek(kinds, vals, head, cap: int, width: int):
-    """Slice the front ``width`` slots (garbage beyond the live length —
-    callers mask with their own valid count).  ``width`` must not exceed
-    the ring's scratch pad, and ``cap >= 2*width`` (the capacity
-    pre-check's ``4*vlen`` floor covers the widest 2W reduce window)."""
+def _window_at(a, start):
+    """``a``'s ``a.shape[0]`` lanes from ``start`` of ``a`` laid twice end
+    to end, ``start`` clamped to ``[0, a.shape[0]]``: a rotation that
+    costs two ops where ``jnp.roll`` traces a dozen."""
+    import jax.lax as lax
+    n = a.shape[0]
+    return lax.dynamic_slice(lax.concatenate([a, a], 0), (start,), (n,),
+                             allow_negative_indices=False)
+
+
+def ring_peek(kinds, cols, head, cap: int, width: int):
+    """Slice the front ``width`` slots: the ``(width,)`` kinds and a tuple
+    of ``(width,)`` payload registers, one per column (garbage beyond the
+    live length — callers mask with their own valid count).  ``width``
+    must not exceed the ring's scratch pad, and ``cap >= 2*width`` (the
+    capacity pre-check's ``4*width`` floor covers the widest 2W reduce
+    window)."""
     jnp = _jnp()
     import jax.lax as lax
     pos = head & (cap - 1)
     lane = jnp.arange(width, dtype=jnp.int32)
-    k = lax.dynamic_slice(kinds, (pos,), (width,))
-    v = lax.dynamic_slice(vals, (pos, 0), (width, vals.shape[1]))
     # lanes whose absolute position wraps past cap live at the ring front
-    # (the pad is scratch); pos < cap keeps idx < width, so a static
-    # front slice + gather covers them
-    idx = pos + lane - cap
-    wrapped = idx >= 0
-    fidx = jnp.where(wrapped, idx, 0)
-    k = jnp.where(wrapped, kinds[:width][fidx], k)
-    v = jnp.where(wrapped[:, None], vals[:width][fidx], v)
-    return k, v
+    # (the pad is scratch): lane l >= cap - pos reads front slot
+    # l - (cap - pos), so the static front window rotated by cap - pos
+    # covers them (a rotation, where a gather would index every lane)
+    shift = cap - pos
+    wrapped = lane >= shift
+
+    # (lax, not jnp: every op here is traced once per column of every
+    # ring, and positions are never negative)
+    def read(a):
+        return lax.select(wrapped, _window_at(a[:width], width - shift),
+                          lax.dynamic_slice(a, (pos,), (width,),
+                                            allow_negative_indices=False))
+
+    return read(kinds), tuple(read(c) for c in cols)
 
 
-def ring_push(kinds, vals, tail, used, cap: int, k_buf, v_buf, count):
-    """Write ``count`` front slots of ``(k_buf, v_buf)`` at the tail.
-    Returns ``(kinds, vals, overflow)``; on overflow nothing is written
-    (the caller latches an error flag and the loop halts, so the ring is
-    never corrupted by a wrapped write).
+def ring_push(kinds, cols, tail, used, cap: int, k_buf, v_bufs, count):
+    """Write ``count`` front lanes of ``k_buf`` and of each register of
+    ``v_bufs`` (``(width,)`` each) at the tail.  Returns ``(kinds, cols,
+    overflow)``; on overflow nothing is written (the caller latches an
+    error flag and the loop halts, so the ring is never corrupted by a
+    wrapped write).
 
     Two chained dynamic-update-slices per array — the tail window (which
     may spill into the scratch pad) and the front window for wrapped
@@ -176,53 +199,48 @@ def ring_push(kinds, vals, tail, used, cap: int, k_buf, v_buf, count):
     cnt = jnp.where(over, 0, count)
     keep = lane < cnt
     pos = tail & (cap - 1)
-    oldk = lax.dynamic_slice(kinds, (pos,), (width,))
-    oldv = lax.dynamic_slice(vals, (pos, 0), (width, vals.shape[1]))
-    kinds = lax.dynamic_update_slice(
-        kinds, jnp.where(keep, k_buf, oldk), (pos,))
-    vals = lax.dynamic_update_slice(
-        vals, jnp.where(keep[:, None], v_buf, oldv), (pos, 0))
     # lanes written past cap landed in the scratch pad; re-issue them at
     # the front, which is authoritative for wrapped positions.  A wrap
     # implies pos >= cap - width, so with cap >= 2*width the front window
     # is disjoint from the tail window; front lane j takes pushed lane
-    # j + cap - pos.  When nothing wrapped this rewrites the front
-    # unchanged (kinds[:width] reads the post-update ring, so a pos==0
-    # overlap also round-trips correctly).
-    src = jnp.clip(lane + cap - pos, 0, width - 1)
-    wr = (lane + cap - pos) < cnt
-    fk = jnp.where(wr, k_buf[src], kinds[:width])
-    fv = jnp.where(wr[:, None], v_buf[src], vals[:width])
-    kinds = lax.dynamic_update_slice(kinds, fk, (0,))
-    vals = lax.dynamic_update_slice(vals, fv, (0, 0))
-    return kinds, vals, over
+    # j + cap - pos (the buffer rotated back by cap - pos).  When nothing
+    # wrapped this rewrites the front unchanged (a[:width] reads the
+    # post-update ring, so a pos==0 overlap also round-trips correctly).
+    shift = cap - pos
+    wr = (lane + shift) < cnt
+
+    def write(a, buf):
+        old = lax.dynamic_slice(a, (pos,), (width,),
+                                allow_negative_indices=False)
+        a = lax.dynamic_update_slice(a, lax.select(keep, buf, old), (pos,),
+                                     allow_negative_indices=False)
+        front = lax.select(wr, _window_at(buf, shift), a[:width])
+        return lax.dynamic_update_slice(a, front, (0,))
+
+    return (write(kinds, k_buf),
+            tuple(write(c, b) for c, b in zip(cols, v_bufs)), over)
 
 
 # ---------------------------------------------------------------------------
 # window-level helpers
 # ---------------------------------------------------------------------------
 
-def window_compact(keep, k_in, v_in, out_width: int | None = None):
-    """Stream compaction with a fixed output buffer: surviving lanes pack to
-    the front, ``count`` reports how many; rows past ``count`` are garbage
+def window_compact(keep, k_in, v_in):
+    """Stream compaction of a ``(W,)`` kinds window and its payload
+    registers (a sequence of ``(W,)`` vectors): surviving lanes pack to the
+    front, ``count`` reports how many; lanes past ``count`` are garbage
     (every consumer masks by the count). ``keep`` already folds validity.
 
-    Formulated as a stable sort-by-dropped + gather rather than a
-    cumsum-indexed scatter: XLA CPU lowers the scatter to a bounds-checked
-    per-row loop (~10x the cost of the sorted gather), and compaction is on
-    the per-fire critical path of the fused loop."""
+    Formulated as one stable sort keyed by the dropped flag that carries
+    every register with it, rather than a cumsum-indexed scatter (XLA CPU
+    lowers the scatter to a bounds-checked per-row loop, ~10x the cost) or
+    an argsort and a gather per register: compaction is on the per-fire
+    critical path of the fused loop."""
     jnp = _jnp()
-    n_in = keep.shape[0]
-    out_width = out_width or n_in
-    kv = jnp.concatenate([k_in[:, None], v_in], axis=1)
-    perm = jnp.argsort(~keep, stable=True)
-    out = jnp.take(kv, perm, axis=0, mode="clip")
-    if out_width < n_in:
-        out = out[:out_width]
-    elif out_width > n_in:
-        out = jnp.concatenate(
-            [out, jnp.zeros((out_width - n_in, kv.shape[1]), jnp.int32)])
-    return out[:, 0], out[:, 1:], keep.sum().astype(jnp.int32)
+    import jax.lax as lax
+    out = lax.sort(((~keep).astype(jnp.int32), k_in, *v_in),
+                   is_stable=True, num_keys=1)
+    return out[1], tuple(out[2:]), keep.sum().astype(jnp.int32)
 
 
 def leading_run(mask, n):
@@ -291,9 +309,9 @@ def segment_reduce_window(kinds, vals, rids, n, op: str, init: int,
     v2 = jnp.stack([jnp.where(emit, g[:W], 0),
                     jnp.zeros(W, jnp.int32)], axis=1).reshape(-1)
     r2 = jnp.stack([brid, brid], axis=1).reshape(-1)
-    out_k, out_v, count = window_compact(k2 != NOTHING, k2,
-                                         jnp.stack([v2, r2], axis=1))
-    return out_k, out_v[:, 0], out_v[:, 1], count, new_acc, new_open
+    out_k, (out_v, out_r), count = window_compact(k2 != NOTHING, k2,
+                                                  (v2, r2))
+    return out_k, out_v, out_r, count, new_acc, new_open
 
 
 def atomic_add_window(mem, addr, delta, ok, base_lane_key):
@@ -303,14 +321,17 @@ def atomic_add_window(mem, addr, delta, ok, base_lane_key):
 
     ``addr`` is already rebased/bounded; ``ok`` masks the participating
     lanes.  Returns ``(mem', old)`` with ``old`` zero on non-ok lanes.
-    ``base_lane_key`` is a (W,) iota used to make the address sort stable.
+    ``base_lane_key`` is a (W,) iota: the lane numbers, which mark where
+    each address's run starts in the sorted window.
     """
     jnp = _jnp()
     W = addr.shape[0]
     big = jnp.int32(mem.shape[0] + 1)
     key = jnp.where(ok, addr, big)
     # stable sort by address: ok lanes grouped by address, lane order kept
-    order = jnp.argsort(key * jnp.int32(W) + base_lane_key)
+    # (a stable sort, not ``key * W + lane``, which overflows int32 once
+    # the array times the window passes 2^31)
+    order = jnp.argsort(key, stable=True)
     sa = addr[order]
     sd = jnp.where(ok, delta, 0)[order]
     sok = ok[order]

@@ -88,6 +88,26 @@ def test_batched_matches_matrix(name):
             f"{name}: request {rid} lane stats"
 
 
+def check_resident_batch(compiled, reqs, name):
+    """One resident launch of ``reqs`` against the windowed numpy oracle's
+    fused batch: one launch, each request's DRAM bit-identical, aggregate
+    lane stats equal.  Returns ``(oracle, resident)``."""
+    bw = compiled.execute_batch(reqs, backend="numpy", replicas=1)
+    br = compiled.execute_batch(reqs, execution="resident")
+    b = len(reqs)
+    assert br.report.execution == "resident"
+    assert br.vm.launches == 1
+    for rid, (ew, er) in enumerate(zip(bw, br)):
+        for arr in ew.dram:
+            np.testing.assert_array_equal(
+                er.dram[arr], ew.dram[arr],
+                err_msg=f"{name} b={b}: request {rid} '{arr}' resident batch")
+    assert {k: int(br.report.stats.get(k, 0)) for k in LANE_STATS} == \
+        {k: int(bw.report.stats.get(k, 0)) for k in LANE_STATS}, \
+        f"{name} b={b}: resident batch aggregate lane stats"
+    return bw, br
+
+
 @pytest.mark.parametrize("name", sorted(ALL_APPS))
 def test_resident_matches_oracle(name, jax_backend):
     """Resident execution (one fused device launch, DESIGN.md §9) vs the
@@ -117,18 +137,7 @@ def test_resident_matches_oracle(name, jax_backend):
 
     # fused batch: de-interleaves to the same per-request images
     reqs = [(app.dram_init, app.params)] * 3
-    bw = compiled.execute_batch(reqs, backend="numpy", replicas=1)
-    br = compiled.execute_batch(reqs, execution="resident")
-    assert br.report.execution == "resident"
-    assert br.vm.launches == 1
-    for rid, (ew, er) in enumerate(zip(bw, br)):
-        for arr in ew.dram:
-            np.testing.assert_array_equal(
-                er.dram[arr], ew.dram[arr],
-                err_msg=f"{name}: request {rid} '{arr}' resident batch")
-    assert {k: int(br.report.stats.get(k, 0)) for k in LANE_STATS} == \
-        {k: int(bw.report.stats.get(k, 0)) for k in LANE_STATS}, \
-        f"{name}: resident batch aggregate lane stats"
+    bw, br = check_resident_batch(compiled, reqs, name)
 
     # replicated windowed executor agrees too (it is itself bit-identical
     # to the fused path; this closes the triangle on the resident launch)
@@ -150,10 +159,16 @@ def test_batched_bit_identity_jax(name, jax_backend):
                             **app.statics).compile(jax_backend)
     ref = compiled.execute(dict(app.dram_init), app.params)
     for batch in (2, 5):
-        bx = compiled.execute_batch([(app.dram_init, app.params)] * batch)
-        for rid, ex in enumerate(bx):
-            for arr in ref.dram:
-                np.testing.assert_array_equal(
-                    ex.dram[arr], ref.dram[arr],
-                    err_msg=f"{name} b={batch} req={rid}: '{arr}' (jax)")
-            assert ex.report.stats == ref.vm.request_stats(0)
+        check_windowed_batch(compiled, ref, app, batch, name)
+
+
+def check_windowed_batch(compiled, ref, app, batch, name):
+    """A windowed fused launch of ``batch`` copies of ``app``'s request
+    de-interleaves to the solo run ``ref``: DRAM and per-request stats."""
+    bx = compiled.execute_batch([(app.dram_init, app.params)] * batch)
+    for rid, ex in enumerate(bx):
+        for arr in ref.dram:
+            np.testing.assert_array_equal(
+                ex.dram[arr], ref.dram[arr],
+                err_msg=f"{name} b={batch} req={rid}: '{arr}' (jax)")
+        assert ex.report.stats == ref.vm.request_stats(0)

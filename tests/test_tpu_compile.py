@@ -76,9 +76,9 @@ def _compile_for_v5e(fn, arrays, params, statics, n_requests, one_chip,
     state, operands, _ = dp._init_state(fused, [dict(params)] * n_requests)
 
     def specs(tree):
-        return {k: jax.ShapeDtypeStruct(np.shape(v), v.dtype,
-                                        sharding=one_chip)
-                for k, v in tree.items()}
+        return jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(np.shape(v), v.dtype,
+                                           sharding=one_chip), tree)
 
     exe = dp._jit_run.lower(specs(state), specs(operands)).compile()
     sizes = {n: d.size for n, d in result.dfg.dram.items()}
